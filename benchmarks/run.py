@@ -21,6 +21,8 @@ import inspect
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 from benchmarks import (
     chaos,
     collectives,
@@ -49,6 +51,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="full timesteps for measured benchmarks")

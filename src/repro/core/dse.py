@@ -13,13 +13,17 @@ fixed-geometry MVM engine (Brainwave's hv=400, rv=40, ru=6) that fragments
 
 so the search is over ``bh`` under a VMEM-residency constraint, with an
 analytic latency model built from the hardware constants in repro.hw.
-``fragmentation`` reproduces Fig. 4's utilization comparison.
+VMEM is counted in padded (sublane, 128-lane) tiles against
+``hw.vmem_budget``, the ``vmem_limit_bytes`` every kernel requests, and
+only tiles Mosaic accepts are proposed (128-lane multiples, or a whole
+axis).  ``fragmentation`` reproduces Fig. 4's utilization comparison.
 
 PR 9 widens the same :class:`Plan` record to the other three Pallas
 kernels so ``ServingPlan.tile_plans`` can carry every kernel's BlockSpec
 geometry: ``bq``/``bk`` for flash_attention (query/KV tile rows, searched
 by :func:`best_attn_plan`) and ``bm``/``bn``/``bk`` for matmul_int8
-(output/contraction tiles, :func:`best_matmul_plan`).  Fields a given
+(output/contraction tiles, :func:`best_matmul_plan`); ``rwkv6_step``
+gets a head-tile search (:func:`best_rwkv_plan`).  Fields a given
 kernel does not use stay at their zero default and are stripped from the
 serialized form by :func:`plan_dict`, so recurrent-cell plan dicts keep
 the exact key set the committed BENCH trajectories embed.
@@ -95,9 +99,23 @@ def _pad(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+# sublane rows of one (rows, 128) VMEM tile, by element size in bytes
+_SUBLANES = {4: SUBLANE, 2: 2 * SUBLANE, 1: 4 * SUBLANE}
+
+
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) block as Mosaic lays it out: rows
+    padded to the dtype's sublane count, cols to the 128-lane width."""
+    return _pad(rows, _SUBLANES[itemsize]) * _pad(cols, MXU) * itemsize
+
+
 def tile_vmem_bytes(cfg: RNNCellConfig, bh: int, *,
                     max_batch: Optional[int] = None) -> int:
-    """VMEM bytes claimed per grid step (weights + state + io).
+    """VMEM bytes the fused RNN kernel claims per grid step, in padded
+    tiles: the per-gate weight blocks (single-buffered when one tile
+    covers H, double-buffered when streamed), their bf16 widening when
+    stored int8, the h/c state and its blocks, the x/y blocks and the
+    per-gate scale/bias rows.
 
     ``max_batch`` overrides ``cfg.batch``: the serving engine decodes
     ``max_batch`` slots per step, so the h/c state and io buffers scale
@@ -105,13 +123,18 @@ def tile_vmem_bytes(cfg: RNNCellConfig, bh: int, *,
     g, H, D = cfg.n_gates, cfg.hidden, cfg.d
     B = cfg.batch if max_batch is None else max_batch
     wbytes = 1 if cfg.precision in ("int8", "blocked_fp") else 2
-    w_block = (D + H) * g * bh * wbytes
     n_tiles = H // bh
-    weights = w_block * (1 if n_tiles == 1 else 2)   # double-buffer if streaming
-    state = (2 * B * H + B * H) * 4                  # h double buffer + c
-    io = B * (D + bh) * 2 * 2
-    scales = 2 * g * bh * 4 + 2 * g * bh * 4
-    return weights + state + io + scales
+    w_block = g * (_tile_bytes(D, bh, wbytes) + _tile_bytes(H, bh, wbytes))
+    weights = w_block * (1 if n_tiles == 1 else 2)
+    widened = (g * (_tile_bytes(D, bh, 2) + _tile_bytes(H, bh, 2))
+               if wbytes == 1 else 0)
+    # h parity double buffer + c scratch, h0/c0 blocks, hT/cT blocks
+    state = (3 * _tile_bytes(B, H, 4) + 4 * _tile_bytes(B, H, 4)
+             + 4 * _tile_bytes(B, bh, 4))
+    io = 2 * _tile_bytes(B, D, 2) + 2 * _tile_bytes(B, bh, 2)
+    n_vec = 3 if cfg.cell == "lstm" else 4           # s_x, s_h, biases
+    scales = 2 * n_vec * _tile_bytes(g, bh, 4)
+    return weights + widened + state + io + scales
 
 
 def plan_metrics(cfg: RNNCellConfig, bh: int,
@@ -169,15 +192,16 @@ def plan_metrics(cfg: RNNCellConfig, bh: int,
 
 
 def candidate_tiles(H: int) -> List[int]:
+    """H tiles the TPU lays out natively: power-of-two multiples of the
+    128-lane width that divide H, plus H itself (always legal: a block
+    spanning a whole axis needs no alignment)."""
     c = []
-    bh = SUBLANE
-    while bh <= H:
+    bh = MXU
+    while bh < H:
         if H % bh == 0:
             c.append(bh)
         bh *= 2
-    if H not in c and H % SUBLANE == 0:
-        c.append(H)
-    return c or [H]
+    return c + [H]
 
 
 def search(cfg: RNNCellConfig, spec: hw.HardwareSpec = hw.DEFAULT, *,
@@ -193,6 +217,52 @@ def best_plan(cfg: RNNCellConfig, spec: hw.HardwareSpec = hw.DEFAULT, *,
     if not plans:  # weights can never be resident; stream with big tiles
         plans = search(cfg, spec, max_batch=max_batch)
     return min(plans, key=lambda p: p.step_latency_s)
+
+
+# ---------------------------------------------------------------------------
+# rwkv6_step head-tile search
+# ---------------------------------------------------------------------------
+
+
+def candidate_head_tiles(n_heads: int) -> List[int]:
+    """Head tiles the rwkv6_step BlockSpecs allow on a TPU: multiples of
+    the 8-row sublane count that divide the head count, plus all heads."""
+    return [t for t in range(SUBLANE, n_heads, SUBLANE)
+            if n_heads % t == 0] + [n_heads]
+
+
+def rwkv_tile_vmem_bytes(head_tile: int, head_dim: int, batch: int) -> int:
+    """VMEM per rwkv6_step grid step: the (B, bh, K, V) f32 state block in
+    and out (double-buffered) plus four state-sized temporaries (k v^T,
+    the bonus read, r * read, the decayed state), with V padded to 128
+    lanes; and the per-token r/k/w/v/y and bonus blocks."""
+    state = batch * head_tile * _tile_bytes(head_dim, head_dim, 4)
+    step = batch * _tile_bytes(head_tile, head_dim, 4)
+    return 8 * state + 2 * 5 * step + 2 * _tile_bytes(head_tile, head_dim, 4)
+
+
+def rwkv_plan_metrics(n_heads: int, head_dim: int, head_tile: int,
+                      batch: int, spec: hw.HardwareSpec = hw.DEFAULT
+                      ) -> Plan:
+    """Score one head tile.  ``bh`` is in hidden units (head_tile x
+    head_dim), the convention ``kernels.rwkv_step.ops.head_tile`` reads."""
+    n_tiles = n_heads // head_tile
+    vmem = rwkv_tile_vmem_bytes(head_tile, head_dim, batch)
+    state_bytes = 2 * batch * n_heads * head_dim * head_dim * 4
+    hbm_s = state_bytes / spec.hbm_bw
+    overhead_s = n_tiles * _STEP_OVERHEAD_S
+    return Plan(bh=head_tile * head_dim, n_tiles=n_tiles, vmem_bytes=vmem,
+                resident=vmem <= hw.vmem_budget(spec),
+                step_latency_s=hbm_s + overhead_s, util=1.0,
+                bound="latency" if overhead_s > hbm_s else "hbm")
+
+
+def best_rwkv_plan(n_heads: int, head_dim: int, batch: int,
+                   spec: hw.HardwareSpec = hw.DEFAULT) -> Plan:
+    plans = [rwkv_plan_metrics(n_heads, head_dim, t, batch, spec)
+             for t in candidate_head_tiles(n_heads)]
+    feasible = [p for p in plans if p.resident] or plans[:1]
+    return min(feasible, key=lambda p: p.step_latency_s)
 
 
 # ---------------------------------------------------------------------------

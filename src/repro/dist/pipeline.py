@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro._jax_compat import shard_map_compat
 
 
 def stack_stage_params(stages: Sequence) -> jax.Array:
@@ -62,7 +61,7 @@ def pipeline_apply(stage_fn: Callable, params, x: jax.Array, mesh: Mesh,
         # only the last stage wrote non-zeros -> psum replicates its rows
         return jax.lax.psum(out, axis)
 
-    fn = shard_map_compat(schedule, mesh, in_specs=(P(axis), P()),
-                          out_specs=P())
+    fn = jax.shard_map(schedule, mesh=mesh, in_specs=(P(axis), P()),
+                       out_specs=P(), check_vma=False)
     xs = x.reshape(n_micro, batch // n_micro, *x.shape[1:])
     return fn(params, xs).reshape(batch, *x.shape[1:])

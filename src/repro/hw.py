@@ -1,17 +1,21 @@
-"""Hardware model of the target platform.
+"""Hardware specs: the chips the Pallas kernels are tiled for.
 
-The container executes on CPU; TPU v5e-class chips are the *target* for
-which we lower, tile, and budget.  All roofline arithmetic in
-:mod:`repro.launch.roofline` and all DSE cost models in :mod:`repro.core.dse`
-read their constants from here so there is exactly one source of truth.
+The serving path runs on a TPU; tests and planning also run on the CPU,
+where the Pallas kernels execute in interpret mode.  A run on a chip takes
+its spec from the device itself (:func:`spec_for_device`, keyed by the
+``device_kind`` JAX reports); planning off-chip names a spec
+(:func:`get_spec`, ``launch.serve --hw-spec``) or uses :data:`DEFAULT`.
+An unknown device kind is an error, never a default.
 
-The constants mirror the assignment spec:
-  * 197 TFLOP/s bf16 per chip (394 TOP/s int8),
-  * 819 GB/s HBM bandwidth,
-  * ~50 GB/s per ICI link,
-and the memory hierarchy parameters used by the Pallas kernels
-(HBM -> VMEM -> VREG), which replace the paper's
-(DRAM -> scratchpad/PMU -> pipeline-register/PCU) hierarchy on Plasticine.
+All roofline arithmetic in :mod:`repro.launch.roofline` and all DSE cost
+models in :mod:`repro.core.dse` read their constants from here.  The TPU
+v5e peaks are the published ones (Google Cloud, "TPU v5e"): 197 TFLOP/s
+bf16 and 393 TOP/s int8 per chip, 16 GB of HBM at 819 GB/s.  VMEM is
+what the chip's compiler reports (128 MiB); :func:`vmem_budget` is the
+share of it one kernel asks Mosaic for (``vmem_limit_bytes``) and the
+ceiling the DSE sizes tiles against.  The memory hierarchy (HBM -> VMEM ->
+VREG) replaces the paper's (DRAM -> scratchpad/PMU -> pipeline
+register/PCU) hierarchy on Plasticine.
 """
 
 from __future__ import annotations
@@ -65,16 +69,13 @@ class HardwareSpec:
         return nbytes / (self.ici_link_bw * self.ici_links)
 
 
-# TPU v5e-class target.  VMEM capacity is the order-of-magnitude budget the
-# Pallas BlockSpecs are sized against; roughly half is usable once the
-# pipelining machinery double-buffers every operand.
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_bf16_flops=197e12,
-    peak_int8_ops=394e12,
+    peak_int8_ops=393e12,
     hbm_bytes=16e9,
     hbm_bw=819e9,
-    vmem_bytes=64 * 2**20,
+    vmem_bytes=128 * 2**20,
     vmem_bw=10e12,
     ici_link_bw=50e9,
     ici_links=4,
@@ -97,10 +98,18 @@ PLASTICINE = HardwareSpec(
     dcn_bw=0.0,
 )
 
+# the planning target when no spec is named and no chip is attached
 DEFAULT = TPU_V5E
 
 # name -> spec, for CLI flags (launch.serve --hw-spec) and plan provenance
 SPECS = {spec.name: spec for spec in (TPU_V5E, PLASTICINE)}
+
+# device_kind, as jax.devices()[i].device_kind reports it -> spec
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+# share of VMEM one kernel claims; Mosaic keeps the rest for its own
+# internal scratch and spills
+VMEM_FRACTION = 0.75
 
 
 def get_spec(name: str) -> HardwareSpec:
@@ -110,6 +119,18 @@ def get_spec(name: str) -> HardwareSpec:
     return SPECS[name]
 
 
-def vmem_budget(hw: HardwareSpec = DEFAULT, fraction: float = 0.5) -> int:
-    """Usable VMEM once double buffering is accounted for."""
-    return int(hw.vmem_bytes * fraction)
+def spec_for_device(device) -> HardwareSpec:
+    """The spec of an attached device, by its ``device_kind``."""
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise KeyError(f"no hardware spec for device_kind {kind!r} "
+                       f"(platform {device.platform}); known kinds: "
+                       f"{sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[kind]
+
+
+def vmem_budget(hw: HardwareSpec = DEFAULT) -> int:
+    """VMEM bytes one Pallas kernel may use: every kernel passes this as
+    ``vmem_limit_bytes`` and the DSE only calls a tile resident when its
+    working set fits under it."""
+    return int(hw.vmem_bytes * VMEM_FRACTION)

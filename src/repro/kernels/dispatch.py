@@ -5,13 +5,18 @@ A ``ServingPlan.tile_plans`` entry (one dict per kernel kind, produced
 by ``planner.tile_plans_for`` / ``core.dse``) may carry an ``impl``
 field:
 
-  * ``"auto"`` (default) — use the Pallas kernel only on a TPU backend;
-    everywhere else keep the pure-jnp reference path.  CPU runs (tests,
-    the committed BENCH trajectories, the virtual-clock scheduler) stay
+  * ``"auto"`` (default) — the Pallas kernel on a TPU backend, the
+    pure-jnp reference path everywhere else.  CPU runs (tests, the
+    committed BENCH trajectories, the virtual-clock scheduler) stay
     byte-identical to a plan with no tile_plans at all.
   * ``"jnp"`` — force the reference path.
-  * ``"pallas"`` — force the Pallas kernel; off-TPU it runs in
-    interpret mode (the mode the parity tests and smoke probes use).
+  * ``"pallas"`` — force the Pallas kernel: compiled by Mosaic on a TPU,
+    run in interpret mode on the CPU (the mode the parity tests use).
+    Any other backend is an error.
+
+On a TPU, ``launch.serve.resolve_plan`` gives a plan with no tile_plans
+the planner's plans for the attached chip, so the plain CLI runs the
+kernels; :func:`kernel_impls` reports which ones a plan runs.
 
 The tile fields themselves (``bh``/``bq``/``bk``/``bm``/``bn``,
 ``persistent``) are read by each kernel's ops wrapper via
@@ -21,7 +26,7 @@ The tile fields themselves (``bh``/``bq``/``bk``/``bm``/``bn``,
 
 from __future__ import annotations
 
-from typing import Mapping, Optional
+from typing import Dict, Mapping, Optional
 
 import jax
 
@@ -47,9 +52,23 @@ def pallas_active(entry: Optional[Mapping[str, object]]) -> bool:
     return entry is not None and resolve_impl(entry) == "pallas"
 
 
+def kernel_impls(tile_plans: Optional[Mapping[str, Mapping[str, object]]]
+                 ) -> Dict[str, str]:
+    """``{kind: "pallas" | "jnp"}`` for every entry of a plan's
+    ``tile_plans`` on the current backend (the serve banner prints it)."""
+    return {kind: resolve_impl(entry)
+            for kind, entry in sorted((tile_plans or {}).items())}
+
+
 def interpret_mode() -> bool:
-    """Pallas interpret flag for the current backend (True off-TPU)."""
-    return not on_tpu()
+    """Pallas interpret flag: True on the CPU, False on a TPU.  The
+    kernels are Mosaic (TPU) kernels, so any other backend raises."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"the Pallas kernels compile for TPU and "
+                           f"interpret on CPU; backend {backend!r} is "
+                           f"neither")
+    return backend == "cpu"
 
 
 def tile_arg(entry: Optional[Mapping[str, object]], name: str,
@@ -61,4 +80,4 @@ def tile_arg(entry: Optional[Mapping[str, object]], name: str,
 
 
 __all__ = ["VALID_IMPLS", "on_tpu", "resolve_impl", "pallas_active",
-           "interpret_mode", "tile_arg"]
+           "kernel_impls", "interpret_mode", "tile_arg"]

@@ -25,9 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._jax_compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
+from repro import hw
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -167,9 +165,10 @@ def flash_attention(q, k, v, q_pos=None, kv_pos=None, *,
             pltpu.VMEM((bq,), F32),
             pltpu.VMEM((bq, d), F32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=hw.vmem_budget()),
         interpret=interpret,
     )
     if q_pos is None and kv_pos is None:

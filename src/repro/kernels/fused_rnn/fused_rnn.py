@@ -5,10 +5,12 @@ hierarchy (DESIGN.md §Hardware-adaptation):
 
   Plasticine                         TPU (this kernel)
   ----------------------------------- -----------------------------------
-  weights resident in PMU scratchpads  weight blocks resident in VMEM; the
-                                       BlockSpec index map is constant in t,
-                                       so Pallas fetches each block from HBM
-                                       once and reuses it for all T steps
+  weights resident in PMU scratchpads  weight blocks resident in VMEM; with
+                                       one H tile the index map is constant
+                                       in t, so Pallas fetches each block
+                                       from HBM once for all T steps (with
+                                       several tiles, every step streams
+                                       them)
   per-element LSTM-1 dataflow          per-tile fused dataflow: gate dots,
                                        scale/bias, nonlinearities, c/h
                                        update in one kernel body (VREGs)
@@ -24,49 +26,69 @@ hierarchy (DESIGN.md §Hardware-adaptation):
 Grid: (T, H/bh), executed sequentially ("arbitrary" semantics) — t-major,
 tile-minor, which is exactly the paper's loop nest in Fig. 5.
 
-Weight layout: w_x (D, G, H), w_h (H, G, H); gate order (i, j, f, o) for
-LSTM, (r, z, n) for GRU.  Scales are per (gate, unit) as produced by
-``repro.core.cells.quantize_weights``.
+Weight layout: callers pass w_x (D, G, H), w_h (H, G, H) with gate order
+(i, j, f, o) for LSTM, (r, z, n) for GRU, and per (gate, unit) scales
+(G, H) as produced by ``repro.core.cells.quantize_weights``.  The kernel
+reads them as (D, G*H) / (H, G*H) — gates folded into the lane axis — and
+takes one (rows, bh) block per gate, so every weight block is lane-dense
+and int8 blocks never pad a size-G sublane axis up to the 32-row int8
+tile.  On a TPU, ``bh`` must be a multiple of 128 (``core.dse`` only
+proposes such tiles).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._jax_compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
+from repro import hw
 
 F32 = jnp.float32
 
 
-def _gates_matmul(x, h_prev, wx_ref, wh_ref, sx_ref, sh_ref, G, bh):
-    """(B,D)x(D,G*bh) + (B,H)x(H,G*bh) with int8->bf16 widening and f32
-    accumulation; returns the two pre-activation halves (B, G, bh)."""
-    B = x.shape[0]
-    wx = wx_ref[...].reshape(wx_ref.shape[0], G * bh)
-    wh = wh_ref[...].reshape(wh_ref.shape[0], G * bh)
-    zx = jax.lax.dot_general(
-        x.astype(jnp.bfloat16), wx.astype(jnp.bfloat16),
-        (((1,), (0,)), ((), ())), preferred_element_type=F32)
-    zh = jax.lax.dot_general(
-        h_prev.astype(jnp.bfloat16), wh.astype(jnp.bfloat16),
-        (((1,), (0,)), ((), ())), preferred_element_type=F32)
-    zx = zx.reshape(B, G, bh) * sx_ref[...]
-    zh = zh.reshape(B, G, bh) * sh_ref[...]
+def _gates(x, h_prev, wx_refs, wh_refs, sx_ref, sh_ref):
+    """Per-gate pre-activations: ((B,D)x(D,bh) * s_x, (B,H)x(H,bh) * s_h)
+    for each gate, int8/bf16 weights widened to bf16, f32 accumulation."""
+    xb = x.astype(jnp.bfloat16)
+    hb = h_prev.astype(jnp.bfloat16)
+
+    def mm(a, w_ref):
+        return jax.lax.dot_general(
+            a, w_ref[...].astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
+
+    zx = [mm(xb, r) * sx_ref[g:g + 1, :] for g, r in enumerate(wx_refs)]
+    zh = [mm(hb, r) * sh_ref[g:g + 1, :] for g, r in enumerate(wh_refs)]
     return zx, zh
 
 
-def _lstm_kernel(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, b_ref,
-                 h0_ref, c0_ref,
-                 y_ref, hT_ref, cT_ref,
-                 h_scr, c_scr, *, bh: int):
+def _lstm_math(zx, zh, b_ref, c_old):
+    z = [zx[g] + zh[g] + b_ref[g:g + 1, :] for g in range(4)]
+    i = jax.nn.sigmoid(z[0])
+    j = jnp.tanh(z[1])
+    f = jax.nn.sigmoid(z[2])
+    o = jax.nn.sigmoid(z[3])
+    c_new = f * c_old + i * j
+    return o * jnp.tanh(c_new), c_new
+
+
+def _gru_math(zx, zh, bx_ref, bh_ref, h_old):
+    zx = [zx[g] + bx_ref[g:g + 1, :] for g in range(3)]
+    zh = [zh[g] + bh_ref[g:g + 1, :] for g in range(3)]
+    r = jax.nn.sigmoid(zx[0] + zh[0])
+    z = jax.nn.sigmoid(zx[1] + zh[1])
+    n = jnp.tanh(zx[2] + r * zh[2])
+    return (1 - z) * n + z * h_old
+
+
+def _lstm_kernel(x_ref, *refs, bh: int):
+    wx_refs, wh_refs = refs[0:4], refs[4:8]
+    (sx_ref, sh_ref, b_ref, h0_ref, c0_ref, y_ref, hT_ref, cT_ref,
+     h_scr, c_scr) = refs[8:]
     t = pl.program_id(0)
     hb = pl.program_id(1)
     T = pl.num_programs(0)
@@ -78,20 +100,9 @@ def _lstm_kernel(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, b_ref,
         c_scr[...] = c0_ref[...].astype(F32)
 
     cur = jax.lax.rem(t, 2)
-    h_prev = h_scr[cur]                                    # (B, H)
-    x = x_ref[0]                                           # (B, D)
-    G = 4
-    zx, zh = _gates_matmul(x, h_prev, wx_ref, wh_ref, sx_ref, sh_ref, G, bh)
-    z = zx + zh + b_ref[...]
-    i = jax.nn.sigmoid(z[:, 0])
-    j = jnp.tanh(z[:, 1])
-    f = jax.nn.sigmoid(z[:, 2])
-    o = jax.nn.sigmoid(z[:, 3])
-
-    sl = pl.ds(hb * bh, bh)
-    c_old = c_scr[:, sl]
-    c_new = f * c_old + i * j
-    h_new = o * jnp.tanh(c_new)
+    zx, zh = _gates(x_ref[0], h_scr[cur], wx_refs, wh_refs, sx_ref, sh_ref)
+    sl = pl.ds(pl.multiple_of(hb * bh, bh), bh)
+    h_new, c_new = _lstm_math(zx, zh, b_ref, c_scr[:, sl])
     c_scr[:, sl] = c_new
     h_scr[1 - cur, :, sl] = h_new                          # next step's h
     y_ref[0] = h_new.astype(y_ref.dtype)
@@ -102,10 +113,10 @@ def _lstm_kernel(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, b_ref,
         cT_ref[...] = c_new.astype(cT_ref.dtype)
 
 
-def _gru_kernel(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, bx_ref, bh_ref,
-                h0_ref,
-                y_ref, hT_ref,
-                h_scr, *, bh: int):
+def _gru_kernel(x_ref, *refs, bh: int):
+    wx_refs, wh_refs = refs[0:3], refs[3:6]
+    (sx_ref, sh_ref, bx_ref, bh_ref, h0_ref, y_ref, hT_ref,
+     h_scr) = refs[6:]
     t = pl.program_id(0)
     hb = pl.program_id(1)
     T = pl.num_programs(0)
@@ -116,19 +127,9 @@ def _gru_kernel(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, bx_ref, bh_ref,
         h_scr[1] = h0_ref[...].astype(F32)
 
     cur = jax.lax.rem(t, 2)
-    h_prev = h_scr[cur]
-    x = x_ref[0]
-    G = 3
-    zx, zh = _gates_matmul(x, h_prev, wx_ref, wh_ref, sx_ref, sh_ref, G, bh)
-    zx = zx + bx_ref[...]
-    zh = zh + bh_ref[...]
-    r = jax.nn.sigmoid(zx[:, 0] + zh[:, 0])
-    z = jax.nn.sigmoid(zx[:, 1] + zh[:, 1])
-    n = jnp.tanh(zx[:, 2] + r * zh[:, 2])
-
-    sl = pl.ds(hb * bh, bh)
-    h_old = jax.lax.dynamic_slice_in_dim(h_prev, hb * bh, bh, axis=1)
-    h_new = (1 - z) * n + z * h_old
+    zx, zh = _gates(x_ref[0], h_scr[cur], wx_refs, wh_refs, sx_ref, sh_ref)
+    sl = pl.ds(pl.multiple_of(hb * bh, bh), bh)
+    h_new = _gru_math(zx, zh, bx_ref, bh_ref, h_scr[cur, :, sl])
     h_scr[1 - cur, :, sl] = h_new
     y_ref[0] = h_new.astype(y_ref.dtype)
 
@@ -137,15 +138,15 @@ def _gru_kernel(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, bx_ref, bh_ref,
         hT_ref[...] = h_new.astype(hT_ref.dtype)
 
 
-def _lstm_kernel_persistent(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, b_ref,
-                            h0_ref, c0_ref,
-                            y_ref, hT_ref, cT_ref,
-                            h_scr, c_scr, *, H: int):
+def _lstm_kernel_persistent(x_ref, *refs):
     """Persistent-decode variant (Sparse Persistent RNNs): the whole
     weight matrices live in VMEM for the full device loop — grid is (T,)
     only, there is no H-tile streaming and no double-buffered h parity.
     Requires the DSE to certify the weights fit (tile_vmem_bytes at
     bh == H); math is bit-identical to the streaming kernel at bh == H."""
+    wx_refs, wh_refs = refs[0:4], refs[4:8]
+    (sx_ref, sh_ref, b_ref, h0_ref, c0_ref, y_ref, hT_ref, cT_ref,
+     h_scr, c_scr) = refs[8:]
     t = pl.program_id(0)
     T = pl.num_programs(0)
 
@@ -154,17 +155,8 @@ def _lstm_kernel_persistent(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, b_ref,
         h_scr[...] = h0_ref[...].astype(F32)
         c_scr[...] = c0_ref[...].astype(F32)
 
-    x = x_ref[0]
-    zx, zh = _gates_matmul(x, h_scr[...], wx_ref, wh_ref, sx_ref, sh_ref,
-                           4, H)
-    z = zx + zh + b_ref[...]
-    i = jax.nn.sigmoid(z[:, 0])
-    j = jnp.tanh(z[:, 1])
-    f = jax.nn.sigmoid(z[:, 2])
-    o = jax.nn.sigmoid(z[:, 3])
-
-    c_new = f * c_scr[...] + i * j
-    h_new = o * jnp.tanh(c_new)
+    zx, zh = _gates(x_ref[0], h_scr[...], wx_refs, wh_refs, sx_ref, sh_ref)
+    h_new, c_new = _lstm_math(zx, zh, b_ref, c_scr[...])
     c_scr[...] = c_new
     h_scr[...] = h_new
     y_ref[0] = h_new.astype(y_ref.dtype)
@@ -175,10 +167,10 @@ def _lstm_kernel_persistent(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, b_ref,
         cT_ref[...] = c_new.astype(cT_ref.dtype)
 
 
-def _gru_kernel_persistent(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, bx_ref,
-                           bh_ref, h0_ref,
-                           y_ref, hT_ref,
-                           h_scr, *, H: int):
+def _gru_kernel_persistent(x_ref, *refs):
+    wx_refs, wh_refs = refs[0:3], refs[3:6]
+    (sx_ref, sh_ref, bx_ref, bh_ref, h0_ref, y_ref, hT_ref,
+     h_scr) = refs[6:]
     t = pl.program_id(0)
     T = pl.num_programs(0)
 
@@ -186,16 +178,9 @@ def _gru_kernel_persistent(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, bx_ref,
     def _init():
         h_scr[...] = h0_ref[...].astype(F32)
 
-    x = x_ref[0]
     h_prev = h_scr[...]
-    zx, zh = _gates_matmul(x, h_prev, wx_ref, wh_ref, sx_ref, sh_ref, 3, H)
-    zx = zx + bx_ref[...]
-    zh = zh + bh_ref[...]
-    r = jax.nn.sigmoid(zx[:, 0] + zh[:, 0])
-    z = jax.nn.sigmoid(zx[:, 1] + zh[:, 1])
-    n = jnp.tanh(zx[:, 2] + r * zh[:, 2])
-
-    h_new = (1 - z) * n + z * h_prev
+    zx, zh = _gates(x_ref[0], h_prev, wx_refs, wh_refs, sx_ref, sh_ref)
+    h_new = _gru_math(zx, zh, bx_ref, bh_ref, h_prev)
     h_scr[...] = h_new
     y_ref[0] = h_new.astype(y_ref.dtype)
 
@@ -204,33 +189,67 @@ def _gru_kernel_persistent(x_ref, wx_ref, wh_ref, sx_ref, sh_ref, bx_ref,
         hT_ref[...] = h_new.astype(hT_ref.dtype)
 
 
-def _specs_persistent(D: int, H: int, G: int, B: int):
-    """Whole-array BlockSpecs over a (T,)-only grid: every weight index
-    map is constant, so each operand is fetched exactly once and pinned
-    in VMEM for the entire sync_every device loop."""
-    return dict(
-        x=pl.BlockSpec((1, B, D), lambda t: (t, 0, 0)),
-        wx=pl.BlockSpec((D, G, H), lambda t: (0, 0, 0)),
-        wh=pl.BlockSpec((H, G, H), lambda t: (0, 0, 0)),
-        s=pl.BlockSpec((G, H), lambda t: (0, 0)),
-        state=pl.BlockSpec((B, H), lambda t: (0, 0)),
-        y=pl.BlockSpec((1, B, H), lambda t: (t, 0, 0)),
-    )
+def _call(kernel, args, *, G: int, bh: int, persistent: bool,
+          n_state: int, interpret: bool, name: str):
+    """Shared pallas_call builder for both cells and both variants.
+
+    ``args`` = (x_seq, w_x, w_h, s_x, s_h, *biases, *states) with the
+    weights in the callers' (rows, G, H) layout.  Weight index maps are
+    constant in t, so with a single H tile (always, in the persistent
+    grid) each weight block is fetched once, stays VMEM-resident across
+    every time step (the paper's on-chip-weights requirement) and is
+    single-buffered."""
+    x_seq, w_x, w_h = args[:3]
+    T, B, D = x_seq.shape
+    H = w_h.shape[0]
+    nt = H // bh
+    if persistent:
+        grid, sem = (T,), ("arbitrary",)
+        ix = lambda f: (lambda t: f(t, 0))
+    else:
+        grid, sem = (T, nt), ("arbitrary", "arbitrary")
+        ix = lambda f: f
+    once = dict(pipeline_mode=pl.Buffered(1)) if nt == 1 else {}
+
+    def wspec(rows, g):
+        return pl.BlockSpec((rows, bh), ix(lambda t, h: (0, g * nt + h)),
+                            **once)
+
+    vec = pl.BlockSpec((G, bh), ix(lambda t, h: (0, h)))
+    state = pl.BlockSpec((B, H), ix(lambda t, h: (0, 0)))
+    n_vec = len(args) - 3 - n_state
+    in_specs = ([pl.BlockSpec((1, B, D), ix(lambda t, h: (t, 0, 0)))]
+                + [wspec(D, g) for g in range(G)]
+                + [wspec(H, g) for g in range(G)]
+                + [vec] * n_vec + [state] * n_state)
+    out_specs = ([pl.BlockSpec((1, B, bh), ix(lambda t, h: (t, 0, h)))]
+                 + [pl.BlockSpec((B, bh), ix(lambda t, h: (0, h)))]
+                 * n_state)
+    out_shape = ([jax.ShapeDtypeStruct((T, B, H), jnp.bfloat16)]
+                 + [jax.ShapeDtypeStruct((B, H), F32)] * n_state)
+    scratch = ([pltpu.VMEM((B, H) if persistent else (2, B, H), F32)]
+               + [pltpu.VMEM((B, H), F32)] * (n_state - 1))
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=sem, vmem_limit_bytes=hw.vmem_budget()),
+        interpret=interpret,
+        name=name,
+    )(x_seq, *([w_x.reshape(D, G * H)] * G),
+      *([w_h.reshape(H, G * H)] * G), *args[3:])
 
 
-def _specs(D: int, H: int, G: int, B: int, bh: int):
-    """BlockSpecs shared by both cells.  Weight index maps are constant in
-    t, so weight blocks are HBM-fetched once and stay VMEM-resident across
-    all time steps (the paper's on-chip-weights requirement)."""
-    return dict(
-        x=pl.BlockSpec((1, B, D), lambda t, h: (t, 0, 0)),
-        wx=pl.BlockSpec((D, G, bh), lambda t, h: (0, 0, h)),
-        wh=pl.BlockSpec((H, G, bh), lambda t, h: (0, 0, h)),
-        s=pl.BlockSpec((G, bh), lambda t, h: (0, h)),
-        state=pl.BlockSpec((B, H), lambda t, h: (0, 0)),
-        y=pl.BlockSpec((1, B, bh), lambda t, h: (t, 0, h)),
-        out_state=pl.BlockSpec((B, bh), lambda t, h: (0, h)),
-    )
+def _tile(H: int, bh: int, persistent: bool) -> int:
+    if persistent:
+        return H
+    bh = min(bh, H)
+    assert H % bh == 0, (H, bh)
+    return bh
 
 
 @functools.partial(jax.jit, static_argnames=("bh", "interpret",
@@ -245,53 +264,15 @@ def fused_lstm(x_seq, w_x, w_h, s_x, s_h, b, h0, c0, *,
     ``persistent=True`` switches to the weights-resident variant (grid
     (T,) only, whole matrices pinned in VMEM — caller must have checked
     ``dse.tile_vmem_bytes(cfg, H)`` against the budget)."""
-    T, B, D = x_seq.shape
     H = w_h.shape[0]
-    if persistent:
-        sp = _specs_persistent(D, H, 4, B)
-        return pl.pallas_call(
-            functools.partial(_lstm_kernel_persistent, H=H),
-            grid=(T,),
-            in_specs=[sp["x"], sp["wx"], sp["wh"], sp["s"], sp["s"],
-                      sp["s"], sp["state"], sp["state"]],
-            out_specs=[sp["y"], sp["state"], sp["state"]],
-            out_shape=[
-                jax.ShapeDtypeStruct((T, B, H), jnp.bfloat16),
-                jax.ShapeDtypeStruct((B, H), F32),
-                jax.ShapeDtypeStruct((B, H), F32),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((B, H), F32),
-                pltpu.VMEM((B, H), F32),
-            ],
-            compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=interpret,
-            name="fused_lstm_persistent",
-        )(x_seq, w_x, w_h, s_x, s_h, b, h0, c0)
-    bh = min(bh, H)
-    assert H % bh == 0, (H, bh)
-    sp = _specs(D, H, 4, B, bh)
-    return pl.pallas_call(
-        functools.partial(_lstm_kernel, bh=bh),
-        grid=(T, H // bh),
-        in_specs=[sp["x"], sp["wx"], sp["wh"], sp["s"], sp["s"], sp["s"],
-                  sp["state"], sp["state"]],
-        out_specs=[sp["y"], sp["out_state"], sp["out_state"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, B, H), jnp.bfloat16),
-            jax.ShapeDtypeStruct((B, H), F32),
-            jax.ShapeDtypeStruct((B, H), F32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, B, H), F32),
-            pltpu.VMEM((B, H), F32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-        name="fused_lstm",
-    )(x_seq, w_x, w_h, s_x, s_h, b, h0, c0)
+    bh = _tile(H, bh, persistent)
+    kernel = (_lstm_kernel_persistent if persistent
+              else functools.partial(_lstm_kernel, bh=bh))
+    return _call(kernel, (x_seq, w_x, w_h, s_x, s_h, b, h0, c0), G=4,
+                 bh=bh, persistent=persistent, n_state=2,
+                 interpret=interpret,
+                 name="fused_lstm_persistent" if persistent
+                 else "fused_lstm")
 
 
 @functools.partial(jax.jit, static_argnames=("bh", "interpret",
@@ -302,42 +283,12 @@ def fused_gru(x_seq, w_x, w_h, s_x, s_h, b_x, b_h, h0, *,
     """x_seq (T, B, D); w_x (D, 3, H); s_* (3, H); b_* (3, H); h0 (B, H).
     Returns (y (T, B, H) bf16, h_T (B, H) f32).  See ``fused_lstm`` for
     the ``bh``/``persistent`` contract."""
-    T, B, D = x_seq.shape
     H = w_h.shape[0]
-    if persistent:
-        sp = _specs_persistent(D, H, 3, B)
-        return pl.pallas_call(
-            functools.partial(_gru_kernel_persistent, H=H),
-            grid=(T,),
-            in_specs=[sp["x"], sp["wx"], sp["wh"], sp["s"], sp["s"],
-                      sp["s"], sp["s"], sp["state"]],
-            out_specs=[sp["y"], sp["state"]],
-            out_shape=[
-                jax.ShapeDtypeStruct((T, B, H), jnp.bfloat16),
-                jax.ShapeDtypeStruct((B, H), F32),
-            ],
-            scratch_shapes=[pltpu.VMEM((B, H), F32)],
-            compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=interpret,
-            name="fused_gru_persistent",
-        )(x_seq, w_x, w_h, s_x, s_h, b_x, b_h, h0)
-    bh = min(bh, H)
-    assert H % bh == 0, (H, bh)
-    sp = _specs(D, H, 3, B, bh)
-    return pl.pallas_call(
-        functools.partial(_gru_kernel, bh=bh),
-        grid=(T, H // bh),
-        in_specs=[sp["x"], sp["wx"], sp["wh"], sp["s"], sp["s"], sp["s"],
-                  sp["s"], sp["state"]],
-        out_specs=[sp["y"], sp["out_state"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, B, H), jnp.bfloat16),
-            jax.ShapeDtypeStruct((B, H), F32),
-        ],
-        scratch_shapes=[pltpu.VMEM((2, B, H), F32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-        name="fused_gru",
-    )(x_seq, w_x, w_h, s_x, s_h, b_x, b_h, h0)
+    bh = _tile(H, bh, persistent)
+    kernel = (_gru_kernel_persistent if persistent
+              else functools.partial(_gru_kernel, bh=bh))
+    return _call(kernel, (x_seq, w_x, w_h, s_x, s_h, b_x, b_h, h0), G=3,
+                 bh=bh, persistent=persistent, n_state=1,
+                 interpret=interpret,
+                 name="fused_gru_persistent" if persistent
+                 else "fused_gru")

@@ -21,9 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._jax_compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
+from repro import hw
 
 F32 = jnp.float32
 
@@ -85,8 +83,9 @@ def matmul_w8a16(x, w_q, scale, bias=None, *, act: str = "none",
         out_specs=pl.BlockSpec((bm, bn), lambda im, jn, ik: (im, jn)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.bfloat16),
         scratch_shapes=[pltpu.VMEM((bm, bn), F32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=hw.vmem_budget()),
         interpret=interpret,
         name="matmul_w8a16",
     )(x, w_q, scale.reshape(1, N), bias.reshape(1, N))

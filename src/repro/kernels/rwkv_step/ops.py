@@ -1,22 +1,17 @@
-"""Jit'd wrapper: model-layout adapter for the fused RWKV6 step kernel.
+"""Plan adapter for the fused RWKV6 step kernel.
 
-Consumes the rwkv block's projections ((B, T, d) flat) and drives the
-kernel in the (T, B, H, K) layout; used by the serving path on TPU and
-validated in interpret mode on CPU.  A ``tile_plans["rwkv"]`` entry sets
-the head tile: its ``bh`` is in hidden units (the DSE cell model's H
-rows), converted to whole heads here and snapped to a divisor of the
-head count.
+``repro.models.recurrence.linear_attention_step_planned`` drives the
+kernel in the serving decode step.  A ``tile_plans["rwkv"]`` entry sets
+the head tile: its ``bh`` is in hidden units (``core.dse.best_rwkv_plan``
+counts head_tile x head_dim), converted to whole heads here and snapped to
+a divisor of the head count.
 """
 
 from __future__ import annotations
 
 from typing import Mapping, Optional
 
-import jax
-import jax.numpy as jnp
-
-from repro.kernels.dispatch import interpret_mode, tile_arg
-from repro.kernels.rwkv_step.rwkv_step import rwkv6_step
+from repro.kernels.dispatch import tile_arg
 
 
 def head_tile(n_heads: int, head_dim: int,
@@ -28,18 +23,3 @@ def head_tile(n_heads: int, head_dim: int,
     if not bh_units:
         return n_heads
     return snap_tile(n_heads, max(1, bh_units // head_dim))
-
-
-def serve_wkv(r, k, v, w_log, u, state, *, head_dim: int = 64,
-              interpret=None, plan: Optional[Mapping[str, object]] = None):
-    """r/k/v/w_log: (B, T, d); u: (d,); state: (B, H, hd, hd) f32."""
-    if interpret is None:
-        interpret = interpret_mode()
-    B, T, d = r.shape
-    H = d // head_dim
-    to = lambda x: x.reshape(B, T, H, head_dim).transpose(1, 0, 2, 3)
-    y, state = rwkv6_step(to(r), to(k), to(v), to(w_log),
-                          u.reshape(H, head_dim), state,
-                          bh=head_tile(H, head_dim, plan),
-                          interpret=interpret)
-    return y.transpose(1, 0, 2, 3).reshape(B, T, d), state

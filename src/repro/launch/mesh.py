@@ -10,20 +10,13 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: meshes carry explicit axis types; default all-Auto
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes, devices) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape),
-                             devices=devices)
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

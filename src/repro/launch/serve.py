@@ -62,6 +62,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.dist.sharding import make_sharder
 from repro.models.lm import build_model
@@ -327,15 +328,24 @@ def resolve_plan(args, parser: argparse.ArgumentParser) -> ServingPlan:
     # tile plans are scored at (arch, max_batch, max_len, hardware) —
     # overriding any of those would leave a stale kernel design half, so
     # recompute them; an explicit --hw-spec always recomputes (the whole
-    # point of the flag is rescoring the kernel half for other silicon)
+    # point of the flag is rescoring the kernel half for other silicon).
+    # On a TPU the spec is the attached chip's, and a plan without tile
+    # plans gets the planner's, so the Pallas kernels serve.
     from repro import hw
+    from repro.kernels.dispatch import on_tpu
 
     try:
-        hw_spec = hw.get_spec(args.hw_spec) if args.hw_spec else hw.DEFAULT
+        if args.hw_spec:
+            hw_spec = hw.get_spec(args.hw_spec)
+        elif on_tpu():
+            hw_spec = hw.spec_for_device(jax.devices()[0])
+        else:
+            hw_spec = hw.DEFAULT
     except KeyError as e:
         parser.error(str(e))
     stale = {"arch", "max_batch", "max_len"} & set(overrides)
-    if args.hw_spec or (base.tile_plans and stale):
+    if (args.hw_spec or (base.tile_plans and stale)
+            or (on_tpu() and not base.tile_plans)):
         from repro.plan import planner
 
         tp = planner.tile_plans_for(
@@ -431,7 +441,22 @@ def _serve_fleet(args, parser, plan) -> None:
               f"{args.trace_out} (open at https://ui.perfetto.dev)")
 
 
+def kernel_banner(plan: ServingPlan) -> str:
+    """Which kernel serves each layer kind on this backend, e.g.
+    ``rwkv[bh2048] -> rwkv=pallas (tpu)``."""
+    from repro.kernels.dispatch import kernel_impls
+    from repro.plan.plan import tiles_summary
+
+    backend = jax.default_backend()
+    if not plan.tile_plans:
+        return f"none -> jnp reference path ({backend})"
+    impls = " ".join(f"{k}={v}" for k, v in
+                     kernel_impls(plan.tile_plans).items())
+    return f"{tiles_summary(plan.tile_plans)} -> {impls} ({backend})"
+
+
 def main() -> None:
+    enable_compile_cache()
     parser = build_parser()
     args = parser.parse_args()
 
@@ -467,9 +492,7 @@ def main() -> None:
                          "--checkpoint-dir DIR so it can restart from a "
                          "checkpoint")
     print(f"plan: {plan.summary()}")
-    if plan.tile_plans:
-        from repro.plan.plan import tiles_summary
-        print(f"kernel tiles: {tiles_summary(plan.tile_plans)}")
+    print(f"kernel tiles: {kernel_banner(plan)}")
     if args.save_plan:
         plan_io.save_plan(plan.resolve(), args.save_plan)
         print(f"wrote plan to {args.save_plan}")

@@ -16,6 +16,7 @@ import logging
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.dist.sharding import make_sharder
@@ -26,6 +27,7 @@ from repro.train.loop import TrainLoopConfig, train
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -136,8 +136,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     q: (B, Sq, H, hd); k, v: (B, Skv, K, hd); positions are (B, S) int32.
     Returns (B, Sq, H, hd).  An active ``tile_plan`` routes to the Pallas
-    flash kernel with the plan's bq/bk BlockSpec geometry (single-device
-    path; the jnp fallback below handles sharded execution).
+    flash kernel with the plan's bq/bk BlockSpec geometry (under a mesh,
+    one kernel per head shard via ``shard_map``).
     """
     from repro.kernels.dispatch import pallas_active
 
@@ -147,7 +147,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out = flash_ops.attention(
             q, k, v, causal=causal, window=window,
             softcap=cfg.attn_softcap, q_pos=q_pos, kv_pos=kv_pos,
-            plan=tile_plan)
+            plan=tile_plan, sharder=sharder)
         return sharder.constrain(
             out, "batch", "qseq", "heads", None).astype(q.dtype)
     B, Sq, H, hd = q.shape
@@ -226,7 +226,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """q: (B, H, hd); caches: (B, S, K, hd); kv_pos: (B, S) absolute
     positions (-1 = empty slot); q_pos: (B,).  Returns (B, H, hd).
     An active ``tile_plan`` routes to the split-KV flash-decoding kernel
-    with the plan's bk chunk size."""
+    with the plan's bk chunk size (under a mesh, per cache-sequence shard
+    with a cross-device log-sum-exp merge)."""
     from repro.kernels.dispatch import pallas_active
 
     if pallas_active(tile_plan):
@@ -234,7 +235,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
         return flash_ops.decode(
             q, k_cache, v_cache, kv_pos, q_pos, causal=causal,
-            window=window, softcap=cfg.attn_softcap, plan=tile_plan)
+            window=window, softcap=cfg.attn_softcap, plan=tile_plan,
+            sharder=sharder)
     B, H, hd = q.shape
     S, K = k_cache.shape[1], k_cache.shape[2]
     G = H // K

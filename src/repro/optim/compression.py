@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro._jax_compat import shard_map_compat
 from repro.core.quant import quantize_int8
 
 F32 = jnp.float32
@@ -57,8 +56,8 @@ def compressed_allreduce(grads: Any, mesh: Mesh, axis: str = "data") -> Any:
             out = out[:-pad]
         return out.reshape(g.shape).astype(g.dtype)
 
-    fn = shard_map_compat(lambda t: jax.tree.map(one, t), mesh,
-                          in_specs=P(), out_specs=P())
+    fn = jax.shard_map(lambda t: jax.tree.map(one, t), mesh=mesh,
+                       in_specs=P(), out_specs=P(), check_vma=False)
     return fn(grads)
 
 

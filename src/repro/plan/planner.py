@@ -60,10 +60,6 @@ SYNC_GAIN_MIN = 0.01      # keep growing the chunk while gain >= 1%
 # instead of degenerating to "smallest block always wins"
 PAGE_OVERHEAD_TOKENS = 2.0
 
-# recurrent layer kinds that map onto the paper's RNN-cell tile search
-_RECURRENT_KINDS = ("rwkv", "swa_ssm")
-
-
 # ---------------------------------------------------------------------------
 # Memory + per-tick cost model (full-size config: the deployment target)
 # ---------------------------------------------------------------------------
@@ -254,21 +250,25 @@ def tile_plans_for(arch: str, max_batch: int, spec: hw.HardwareSpec,
     """Embed a ``core.dse`` tile plan per layer kind, scored at the
     serving batch (the kernel-level half of the design point).
 
-    Recurrent kinds run the paper's RNN-cell tile search (3-gate cell at
-    the model width); a plan whose chosen tile keeps the weights VMEM-
-    resident in a single tile is additionally marked ``persistent`` — the
-    fused decode kernel then pins w_h/w_x in VMEM across the whole token
-    loop.  Attention kinds (attn/local) get a bq/bk flash tile plan scored
-    at the config's max sequence.  Every dict is the compact
-    ``dse.plan_dict`` form so unset tile fields never reach the plan
-    (keeps committed plans/BENCH rows byte-stable)."""
+    ``rwkv`` gets the rwkv6_step head-tile search (the (B, bh, K, V)
+    state block is what fills VMEM); ``swa_ssm`` runs the paper's RNN-cell
+    tile search (3-gate cell at the model width), and a plan whose chosen
+    tile keeps the weights VMEM-resident in a single tile is additionally
+    marked ``persistent``.  Attention kinds (attn/local) get a bq/bk flash
+    tile plan scored at the config's max sequence.  Every dict is the
+    compact ``dse.plan_dict`` form so unset tile fields never reach the
+    plan (keeps committed plans/BENCH rows byte-stable)."""
     from repro.core import dse
     from repro.core.cells import RNNCellConfig
 
     cfg = _full_model(arch).cfg
     out: Dict[str, Dict[str, object]] = {}
     for kind in sorted(set(cfg.layer_pattern)):
-        if kind in _RECURRENT_KINDS:
+        if kind == "rwkv":
+            hd = cfg.rwkv.head_dim
+            out[kind] = dse.plan_dict(dse.best_rwkv_plan(
+                cfg.d_model // hd, hd, max_batch, spec))
+        elif kind == "swa_ssm":
             cell = RNNCellConfig("gru", hidden=cfg.d_model,
                                  features=cfg.d_model,
                                  batch=1, precision="bf16")

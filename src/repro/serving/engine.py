@@ -383,6 +383,15 @@ class ServingEngine:
         return cls(model, params, sharder, seed=seed, plan=plan,
                    tracer=tracer)
 
+    def lower_decode(self):
+        """Lower the fused decode-chunk program that :meth:`step` launches,
+        at this engine's shapes, for inspection: ``.compile().as_text()``
+        shows which kernels it holds."""
+        return self._decode_many.lower(
+            self.params, self.sm.cache, self.sm.next_token, self._key,
+            self.sm.active, self.sm.eos, self.sm.remaining,
+            np.int32(self.sync_every), np.bool_(False))
+
     # ------------------------------------------------- back-compat accessors
     @property
     def cache(self):

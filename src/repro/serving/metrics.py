@@ -30,7 +30,9 @@ Everything is computed in ticks and scaled by ``tick_seconds`` at the end,
 so the same aggregation serves both the deterministic virtual-clock mode
 (``tick_seconds=1.0`` — "seconds" are tick units) and wall-clock runs
 (``tick_seconds = measured wall time / ticks``).  Percentiles use the
-nearest-rank method: exact, deterministic, no interpolation.
+nearest-rank method: exact, deterministic, no interpolation.  Means use
+``math.fsum``, which rounds the exact sum once, so they do not depend on
+the order of the samples or on the interpreter's own ``sum``.
 """
 
 from __future__ import annotations
@@ -54,7 +56,7 @@ def percentile(xs: Sequence[float], q: float) -> float:
 
 def _summary(xs: Sequence[float]) -> Dict[str, float]:
     out = {f"p{q}": percentile(xs, q) for q in PERCENTILES}
-    out["mean"] = float(sum(xs) / len(xs)) if xs else math.nan
+    out["mean"] = math.fsum(xs) / len(xs) if xs else math.nan
     out["n"] = len(xs)
     return out
 
@@ -103,7 +105,7 @@ def aggregate(reqs: Sequence[Request], *, ticks: int,
         "ttft": scaled("ttft"),
         "tpot": scaled("tpot"),
         "tokens_per_sec": tokens / span if span > 0 else math.nan,
-        "mean_util": (float(sum(util) / len(util)) if util else math.nan),
+        "mean_util": math.fsum(util) / len(util) if util else math.nan,
     }
     # deadline / preemption blocks: emitted only when the feature was in
     # play, so deadline-less runs aggregate to the historical dict exactly.

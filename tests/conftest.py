@@ -5,12 +5,6 @@ device counts (in their own subprocess env)."""
 import jax
 import pytest
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # no extra deps in the image: install the replay stub
-    from repro import _hypothesis_stub
-    _hypothesis_stub.install()
-
 from repro.dist.sharding import Sharder
 
 

@@ -58,6 +58,7 @@ print("OK")
 def test_compressed_allreduce_int8_wire_format():
     r = subprocess.run([sys.executable, "-c", COMPRESSED_AR],
                        capture_output=True, text=True, timeout=600,
-                       env={**os.environ, "PYTHONPATH": "src"}, cwd=".")
+                       env={**os.environ, "PYTHONPATH": "src",
+                            "JAX_PLATFORMS": "cpu"}, cwd=".")
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

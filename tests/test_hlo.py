@@ -71,6 +71,7 @@ print("OK")
 """
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300,
-                       env={**os.environ, "PYTHONPATH": "src"}, cwd=".")
+                       env={**os.environ, "PYTHONPATH": "src",
+                            "JAX_PLATFORMS": "cpu"}, cwd=".")
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

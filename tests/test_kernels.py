@@ -129,13 +129,13 @@ def test_matmul_w8a16_vs_ref(M, K, N, act, with_bias):
 
 def test_default_bh_is_batch_aware():
     """Regression pin: the serving tile must be scored at the *served*
-    batch.  lstm H=4096 (bf16) wants bh=128 single-lane but the smaller
-    bh=64 tile once 256 slots of state/io claim their VMEM share — the
+    batch.  lstm H=4096 (bf16) wants bh=512 single-lane but the smaller
+    bh=256 tile once 256 slots of state/io claim their VMEM share — the
     old code passed no max_batch and silently served the b=1 tile."""
     from repro.core.dse import best_plan
     cfg = RNNCellConfig("lstm", 4096, precision="bf16")
-    assert rnn_ops.default_bh(cfg, 1) == best_plan(cfg, max_batch=1).bh == 128
-    assert rnn_ops.default_bh(cfg, 256) == 64
+    assert rnn_ops.default_bh(cfg, 1) == best_plan(cfg, max_batch=1).bh == 512
+    assert rnn_ops.default_bh(cfg, 256) == 256
     assert rnn_ops.default_bh(cfg, 256) != best_plan(cfg).bh
 
 

@@ -60,6 +60,7 @@ print("OK")
 def test_gpipe_matches_sequential():
     r = subprocess.run([sys.executable, "-c", CODE], capture_output=True,
                        text=True, timeout=900,
-                       env={**os.environ, "PYTHONPATH": "src"}, cwd=".")
+                       env={**os.environ, "PYTHONPATH": "src",
+                            "JAX_PLATFORMS": "cpu"}, cwd=".")
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout

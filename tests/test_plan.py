@@ -384,11 +384,11 @@ def test_preempt_many_matches_sequential_schedule(built):
 
 def test_tile_search_scores_serving_batch():
     cfg = RNNCellConfig("lstm", 4096, precision="bf16")
-    # regression pin: at batch 1 the big 128-row tile is VMEM-resident;
+    # regression pin: at batch 1 the big 512-lane tile is VMEM-resident;
     # at the serving batch the h/c state squeezes it out and the search
-    # correctly drops to 64-row tiles
-    assert dse.best_plan(cfg).bh == 128
-    assert dse.best_plan(cfg, max_batch=256).bh == 64
+    # correctly drops to 256-lane tiles
+    assert dse.best_plan(cfg).bh == 512
+    assert dse.best_plan(cfg, max_batch=256).bh == 256
     # vmem accounting actually moved
     assert dse.tile_vmem_bytes(cfg, 128, max_batch=256) > \
         dse.tile_vmem_bytes(cfg, 128)

@@ -1,5 +1,6 @@
 """benchmarks/serving_load.py: determinism contract + document schema."""
 
+import math
 import sys
 from pathlib import Path
 
@@ -9,6 +10,24 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmarks import serving_load as sl  # noqa: E402
 from repro.configs import SERVING_LOAD_SWEEP, ServingLoadCell  # noqa: E402
+
+
+def _assert_metrics_match(fresh, committed):
+    """A fresh metrics block against a committed one: keys, counts,
+    tokens and ticks exactly; floats to rel 1e-12, because the committed
+    means were summed in sample order before ``math.fsum`` replaced the
+    interpreter's ``sum``."""
+    if isinstance(committed, dict):
+        assert isinstance(fresh, dict) and set(fresh) == set(committed)
+        for k in committed:
+            _assert_metrics_match(fresh[k], committed[k])
+    elif isinstance(committed, float) and not isinstance(fresh, bool):
+        if math.isnan(committed):
+            assert math.isnan(fresh)
+        else:
+            assert fresh == pytest.approx(committed, rel=1e-12, abs=0)
+    else:
+        assert fresh == committed and type(fresh) is type(committed)
 
 
 def test_sweep_spans_three_families():
@@ -185,7 +204,8 @@ def test_paged_rerun_reproduces_committed_dense_metrics():
         plan=dataclasses.replace(dense_cell.plan, cache_layout="paged:16"))
     fresh = sl.run_cell(paged_cell, duration=doc["duration"],
                         seed=doc["seed"])
-    assert fresh["metrics"] == committed["qwen2.5-14b/b4/r1"]["metrics"]
+    _assert_metrics_match(fresh["metrics"],
+                          committed["qwen2.5-14b/b4/r1"]["metrics"])
 
 
 @pytest.mark.slow
@@ -216,7 +236,7 @@ def test_refactor_matches_committed_trajectory():
     cell = next(c for c in SERVING_LOAD_SWEEP
                 if c.name == "rwkv6-1.6b/b2/r1")
     fresh = sl.run_cell(cell, duration=doc["duration"], seed=doc["seed"])
-    assert fresh["metrics"] == committed[cell.name]["metrics"]
+    _assert_metrics_match(fresh["metrics"], committed[cell.name]["metrics"])
 
 
 @pytest.mark.slow

@@ -103,6 +103,67 @@ def test_sharded_decode_matches_replicated():
     end-to-end under the sharded layout."""
     r = subprocess.run([sys.executable, "-c", SHARDED_DECODE],
                        capture_output=True, text=True, timeout=900,
-                       env={**os.environ, "PYTHONPATH": "src"}, cwd=".")
+                       env={**os.environ, "PYTHONPATH": "src",
+                            "JAX_PLATFORMS": "cpu"}, cwd=".")
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
+SHARDED_FLASH = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import jax.numpy as jnp
+import numpy as np
+from repro.dist.sharding import make_sharder
+from repro.kernels.flash_attention import ops
+from repro.launch.mesh import make_test_mesh
+from repro.testing import reduced_config
+
+cfg = reduced_config("qwen2.5-14b")
+mesh = make_test_mesh((1, 4), ("data", "model"))
+key = jax.random.PRNGKey(0)
+r = lambda i, s: jax.random.normal(jax.random.fold_in(key, i), s, jnp.bfloat16)
+
+# decode: the cache's sequence axis splits over the model axis; each chip
+# runs flash_decode on its slice and the partials merge across chips
+B, S, H, K, d = 2, 512, 4, 2, 64
+q, kc, vc = r(0, (B, H, d)), r(1, (B, S, K, d)), r(2, (B, S, K, d))
+kv_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+kv_pos = jnp.where(jnp.arange(S) % 7 == 3, -1, kv_pos)      # ring holes
+q_pos = jnp.asarray([300, 511], jnp.int32)
+dec = make_sharder(cfg, mesh, "decode")
+assert dec.spec(("batch", "cache_seq", None, None), kc.shape)[1] == "model"
+plan = {"bk": 128}
+one = jax.jit(lambda *a: ops.decode(*a, plan=plan))(q, kc, vc, kv_pos, q_pos)
+four = jax.jit(lambda *a: ops.decode(*a, plan=plan, sharder=dec))(
+    q, kc, vc, kv_pos, q_pos)
+np.testing.assert_allclose(np.asarray(four, np.float32),
+                           np.asarray(one, np.float32), atol=2e-2, rtol=2e-2)
+
+# prefill: heads split over the model axis where the rules map them
+pre = make_sharder(reduced_config("gemma3-12b"), mesh, "prefill")
+assert pre.resolve("heads", 8) == pre.resolve("kv_heads", 4) == ("model",)
+qs, ks, vs = r(3, (1, 256, 8, d)), r(4, (1, 256, 4, d)), r(5, (1, 256, 4, d))
+pos = jnp.arange(256, dtype=jnp.int32)[None]
+att = lambda sh: jax.jit(lambda *a: ops.attention(
+    *a, q_pos=pos, kv_pos=pos, plan={"bq": 128, "bk": 128}, sharder=sh))
+np.testing.assert_allclose(np.asarray(att(pre)(qs, ks, vs), np.float32),
+                           np.asarray(att(None)(qs, ks, vs), np.float32),
+                           atol=2e-2, rtol=2e-2)
+print("OK")
+"""
+
+
+@pytest.mark.slow
+def test_flash_kernels_under_shard_map_match_one_device():
+    """The flash adapters under a mesh sharder (shard_map around the
+    Pallas call, interpret mode here) match the one-device kernels:
+    decode split over the cache's sequence axis with a cross-device
+    log-sum-exp merge, prefill split over heads."""
+    r = subprocess.run([sys.executable, "-c", SHARDED_FLASH],
+                       capture_output=True, text=True, timeout=900,
+                       env={**os.environ, "PYTHONPATH": "src",
+                            "JAX_PLATFORMS": "cpu"}, cwd=".")
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout

@@ -1,4 +1,4 @@
-"""Property-based Sharder tests (hypothesis; replay stub when absent).
+"""Property-based Sharder tests (hypothesis).
 
 Randomized meshes / rules / tensor shapes pin the resolution contract of
 ``repro.dist.sharding.Sharder`` (see its module docstring):
@@ -9,10 +9,6 @@ Randomized meshes / rules / tensor shapes pin the resolution contract of
   always divides the dimension;
 * one mesh axis is never assigned to two dims of the same tensor;
 * the mesh-less Sharder is a strict no-op.
-
-``tests/conftest.py`` installs ``repro._hypothesis_stub`` when the real
-package is missing, so this file runs the genuine shrinking search on CI
-(which installs hypothesis) and a deterministic replay sweep otherwise.
 """
 
 import jax.numpy as jnp

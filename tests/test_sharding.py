@@ -96,6 +96,7 @@ def test_dryrun_machinery_on_8_fake_devices():
     r = subprocess.run([sys.executable, "-c", DRYRUN_SMALL],
                        capture_output=True, text=True, timeout=900,
                        env={**__import__("os").environ,
-                            "PYTHONPATH": "src"}, cwd=".")
+                            "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+                       cwd=".")
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout
