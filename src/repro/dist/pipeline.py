@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-
 def stack_stage_params(stages: Sequence) -> jax.Array:
     """Stack a list of per-stage param trees along a new leading (stage)
     dim, giving the pipeline-sharded layout ``pipeline_apply`` expects."""

@@ -10,7 +10,7 @@ An unknown device kind is an error, never a default.
 All roofline arithmetic in :mod:`repro.launch.roofline` and all DSE cost
 models in :mod:`repro.core.dse` read their constants from here.  The TPU
 v5e peaks are the published ones (Google Cloud, "TPU v5e"): 197 TFLOP/s
-bf16 and 393 TOP/s int8 per chip, 16 GB of HBM at 819 GB/s.  VMEM is
+bf16 and 394 TOP/s int8 per chip, 16 GB of HBM at 819 GB/s.  VMEM is
 what the chip's compiler reports (128 MiB); :func:`vmem_budget` is the
 share of it one kernel asks Mosaic for (``vmem_limit_bytes``) and the
 ceiling the DSE sizes tiles against.  The memory hierarchy (HBM -> VMEM ->
@@ -72,7 +72,7 @@ class HardwareSpec:
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_bf16_flops=197e12,
-    peak_int8_ops=393e12,
+    peak_int8_ops=394e12,
     hbm_bytes=16e9,
     hbm_bw=819e9,
     vmem_bytes=128 * 2**20,
