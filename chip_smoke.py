@@ -23,8 +23,10 @@ Four chips (``--chips 4``):
     each chip must hold about a quarter of the params, and the engine
     must serve a few requests.
 
-Timings printed on the way are labelled smoke timings: one cold run,
-compiles included, not benchmark numbers.  The last line of stdout is
+Timings printed on the way are labelled smoke timings: one run,
+compiles included, not benchmark numbers.  A line before them says how
+many entries the persistent compile cache held at the start, i.e.
+whether the compile times are cold.  The last line of stdout is
 one JSON object ``{"ok": true, "device": {...}}``.  Without a TPU, or
 when any check fails, the script exits non-zero and prints no such line.
 """
@@ -88,7 +90,7 @@ class smoke_timing:
     def __exit__(self, *exc):
         self.seconds = time.perf_counter() - self.t0
         if exc[0] is None:
-            log(f"smoke timing (cold, not a benchmark): {self.what}: "
+            log(f"smoke timing (not a benchmark): {self.what}: "
                 f"{self.seconds:.1f} s")
 
 
@@ -117,6 +119,14 @@ def check_device(min_count: int = 1):
         raise SystemExit(f"chip_smoke: need {min_count} TPU chips, JAX "
                          f"sees {len(devices)}")
     return devices
+
+
+def cache_note(path) -> str:
+    """Where the persistent compile cache is and how full it starts."""
+    if not path:
+        return "compile cache: off"
+    n = sum(1 for _ in Path(path).iterdir()) if Path(path).is_dir() else 0
+    return f"compile cache: {path} ({n} entries at start)"
 
 
 def _resolve(argv):
@@ -377,6 +387,9 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
     devices = check_device(args.chips)
+    import jax
+
+    log(cache_note(jax.config.jax_compilation_cache_dir))
     if args.chips == 4:
         sharded_qwen(devices[:4], args.seed)
     else:

@@ -191,7 +191,7 @@ def _gru_kernel_persistent(x_ref, *refs):
 
 def _call(kernel, args, *, G: int, bh: int, persistent: bool,
           n_state: int, interpret: bool, name: str):
-    """Shared pallas_call builder for both cells and both variants.
+    """The pallas_call shared by both cells and both variants.
 
     ``args`` = (x_seq, w_x, w_h, s_x, s_h, *biases, *states) with the
     weights in the callers' (rows, G, H) layout.  Weight index maps are

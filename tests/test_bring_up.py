@@ -56,6 +56,22 @@ def test_smoke_script_fails_without_tpu(tmp_path, alone):
     assert '"ok"' not in r.stdout
 
 
+@pytest.mark.parametrize("entries", [None, 0, 3],
+                         ids=["off", "missing", "three"])
+def test_smoke_cache_note_counts_entries(tmp_path, entries):
+    """The smoke says whether its compile times start from a warm cache."""
+    path = None if entries is None else tmp_path / "cache"
+    if entries:
+        path.mkdir()
+        for i in range(entries):
+            (path / f"entry{i}").write_bytes(b"")
+    note = chip_smoke.cache_note(path and str(path))
+    if entries is None:
+        assert note == "compile cache: off"
+    else:
+        assert note == f"compile cache: {path} ({entries} entries at start)"
+
+
 # ---------------------------------------------------------------------------
 # compile cache
 # ---------------------------------------------------------------------------
