@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.kernels.dispatch import interpret_mode, tile_arg
 from repro.kernels.fused_rnn.fused_rnn import fused_gru, fused_lstm
+from repro.obs.spans import span
 
 F32 = jnp.float32
 
@@ -62,24 +63,29 @@ def serve(cfg, w: Dict, x_seq: jax.Array, *, bh: int = 0,
         interpret = interpret_mode()
     T, B, D = x_seq.shape
     H = cfg.hidden
-    persistent = bool((plan or {}).get("persistent", False))
-    bh = tile_arg(plan, "bh", bh or 0)
-    if not bh:
-        bh = H if persistent else default_bh(cfg, B)
-    bh = H if persistent else snap_tile(H, bh)
-    wx, wh, s_x, s_h = _weights_for_kernel(cfg, w)
-    if state is None:
-        h0 = jnp.zeros((B, H), F32)
-        c0 = jnp.zeros((B, H), F32)
-    else:
-        h0 = state[0]
-        c0 = state[1] if len(state) > 1 else jnp.zeros((B, H), F32)
-    if cfg.cell == "lstm":
-        y, _, _ = fused_lstm(x_seq, wx, wh, s_x, s_h, w["b"], h0, c0,
+    with span("rnn.plan"):
+        persistent = bool((plan or {}).get("persistent", False))
+        bh = tile_arg(plan, "bh", bh or 0)
+        if not bh:
+            bh = H if persistent else default_bh(cfg, B)
+        bh = H if persistent else snap_tile(H, bh)
+    with span("rnn.operands"):
+        wx, wh, s_x, s_h = _weights_for_kernel(cfg, w)
+        if state is None:
+            h0 = jnp.zeros((B, H), F32)
+            c0 = jnp.zeros((B, H), F32)
+        else:
+            h0 = state[0]
+            c0 = state[1] if len(state) > 1 else jnp.zeros((B, H), F32)
+        b_h = (None if cfg.cell == "lstm"
+               else w.get("b_h", jnp.zeros_like(w["b"])))
+    with span("rnn.launch"):
+        if cfg.cell == "lstm":
+            y, _, _ = fused_lstm(x_seq, wx, wh, s_x, s_h, w["b"], h0, c0,
+                                 bh=bh, interpret=interpret,
+                                 persistent=persistent)
+        else:
+            y, _ = fused_gru(x_seq, wx, wh, s_x, s_h, w["b"], b_h, h0,
                              bh=bh, interpret=interpret,
                              persistent=persistent)
-    else:
-        y, _ = fused_gru(x_seq, wx, wh, s_x, s_h, w["b"],
-                         w.get("b_h", jnp.zeros_like(w["b"])), h0,
-                         bh=bh, interpret=interpret, persistent=persistent)
     return y

@@ -1,6 +1,6 @@
 """`repro.obs`: observability for the serving stack.
 
-Three layers, each usable on its own:
+Four parts, each usable on its own:
 
 * :mod:`repro.obs.registry` — a typed counters/gauges/histograms registry
   (:class:`MetricsRegistry`) that owns every serving-stack counter, plus
@@ -18,7 +18,17 @@ Three layers, each usable on its own:
   :func:`repro.plan.planner.autotune` can replan from *observed*
   traffic instead of a synthetic probe
   (surfaced as ``WorkloadProfile.from_trace`` and
-  ``planner.autotune_from_trace``).
+  ``planner.autotune_from_trace``);
+* :mod:`repro.obs.spans` — :func:`span`, named host spans around each
+  phase of the engine step and of one fused-RNN request
+  (:data:`SPANS`), on the JAX profiler's clock: they appear in a
+  ``jax.profiler`` trace beside the device's ops and cost about a
+  microsecond when no profiler runs.
+
+Clocks: the registry counts events and the live window rolls over engine
+ticks; the tracer and the profile fit use the virtual tick clock, so
+their output never depends on wall time; only the spans use the
+profiler's (wall) clock.
 """
 
 from repro.obs.registry import (  # noqa: F401
@@ -36,3 +46,4 @@ from repro.obs.trace import (  # noqa: F401
     merge_traces,
 )
 from repro.obs.observe import fit_profile  # noqa: F401
+from repro.obs.spans import SPANS, span  # noqa: F401

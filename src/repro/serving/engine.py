@@ -63,7 +63,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-from functools import partial
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
@@ -73,6 +72,7 @@ import numpy as np
 from repro.dist.sharding import Sharder
 from repro.models.lm import LM
 from repro.obs.registry import LiveMetrics, MetricsRegistry
+from repro.obs.spans import span
 from repro.obs.trace import Tracer
 from repro.plan.plan import MIN_BUCKET, ServingPlan
 from repro.serving.sampler import SamplerConfig, split_and_sample
@@ -348,13 +348,21 @@ class ServingEngine:
         self._prefill_blocked = False           # a prefill failed this tick
         self.restored_from: Optional[Dict[str, Any]] = None
         self._key = jax.random.PRNGKey(seed)
-        self._decode_many = jax.jit(
-            partial(_decode_many, model, sharder, self.sampler,
-                    self.max_len, self.sync_every),
-            donate_argnums=1)
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, sharder,
-                                       max_len=self.max_len))
+        sampler, max_len, k = self.sampler, self.max_len, self.sync_every
+
+        # named functions, so the programs are ``jit_decode_program`` and
+        # ``jit_prefill_program`` in traces and compile logs
+        def decode_program(params, cache, tokens, key, active, eos,
+                           remaining, limit, stop_on_free):
+            return _decode_many(model, sharder, sampler, max_len, k,
+                                params, cache, tokens, key, active, eos,
+                                remaining, limit, stop_on_free)
+
+        def prefill_program(params, batch):
+            return model.prefill(params, batch, sharder, max_len=max_len)
+
+        self._decode_many = jax.jit(decode_program, donate_argnums=1)
+        self._prefill = jax.jit(prefill_program)
 
     @classmethod
     def from_plan(cls, plan: ServingPlan, params, *,
@@ -570,11 +578,16 @@ class ServingEngine:
         up to ``min(sync_every, max_ticks)`` fused decode ticks on device
         with a single host sync at the end, report telemetry.  Returns
         False when idle."""
+        with span("engine.step"):
+            return self._step(max_ticks)
+
+    def _step(self, max_ticks: Optional[int]) -> bool:
         budget = self.sync_every if max_ticks is None \
             else max(1, min(int(max_ticks), self.sync_every))
         if self._injector is not None:
             self._apply_due_faults()   # may raise EngineKilled
-        n_instant = self._schedule()
+        with span("engine.schedule"):
+            n_instant = self._schedule()
         if self.tracer is not None:
             self.tracer.counter(self._tick, "queue_depth",
                                 len(self.scheduler))
@@ -597,20 +610,32 @@ class ServingEngine:
             self.tracer.compile(self._tick, "decode", self.max_batch,
                                 self.sync_every)
             self._decode_compile_traced = True
-        # paged layout: extend every occupied slot's block coverage for
-        # the chunk's ring writes before the program launches (dense: no-op)
-        self.sm.ensure_chunk(budget)
-        tokens_in = self._merge_pending_tokens()
-        n, self.sm.cache, self._key, toks, acts, dones = self._decode_many(
-            self.params, self.sm.cache, tokens_in, self._key,
-            self.sm.active, self.sm.eos, self.sm.remaining,
-            np.int32(budget), np.bool_(stop_on_free))
+        with span("engine.launch"):
+            # paged layout: extend every occupied slot's block coverage
+            # for the chunk's ring writes before the program launches
+            # (dense: no-op)
+            self.sm.ensure_chunk(budget)
+            tokens_in = self._merge_pending_tokens()
+            n, self.sm.cache, self._key, toks, acts, dones = \
+                self._decode_many(
+                    self.params, self.sm.cache, tokens_in, self._key,
+                    self.sm.active, self.sm.eos, self.sm.remaining,
+                    np.int32(budget), np.bool_(stop_on_free))
         self._c_decode_chunks.inc()
         # ---- the chunk's single blocking host<->device sync -------------
         # (overlapped admissions' first tokens ride home on the same pull)
-        n, toks, acts, dones, firsts = jax.device_get(
-            (n, toks, acts, dones, [p.first for p in self._pending]))
-        n = int(n)
+        with span("engine.readback"):
+            n, toks, acts, dones, firsts = jax.device_get(
+                (n, toks, acts, dones, [p.first for p in self._pending]))
+        with span("engine.bookkeep"):
+            self._bookkeep(int(n), toks, acts, dones, firsts, active_idx,
+                           n_instant)
+        return True
+
+    def _bookkeep(self, n: int, toks, acts, dones, firsts,
+                  active_idx: List[int], n_instant: int) -> None:
+        """Host side of a chunk's readback: append its tokens, retire
+        finished requests, report its ticks, refresh the slot mirrors."""
         self._c_host_syncs.inc()
         # fault path: a dropped readback discards the whole chunk's tokens
         # (and the overlapped first tokens riding on it) — every slot that
@@ -665,11 +690,6 @@ class ServingEngine:
             self._tick += 1
         if self._fault_mode:
             self._fault_epilogue(bad, dropped, progressed)
-        log.debug("chunk of %d ticks -> tick %d: util=%.2f queued=%d "
-                  "completed=%d total_tokens=%d syncs=%d", n, self._tick,
-                  self.util_history[-1], len(self.scheduler), self.completed,
-                  self.total_tokens, self.host_syncs)
-        return True
 
     # ------------------------------------------------------------- internals
     def _finish(self, req: Request, tick: int) -> None:
@@ -1056,7 +1076,8 @@ class ServingEngine:
                        and not any(r.eos_id is not None
                                    or r.max_new_tokens == 1 for r in fresh))
             for S, reqs in grouped:
-                n_instant += self._prefill_group(S, reqs, free, overlap)
+                with span("engine.prefill"):
+                    n_instant += self._prefill_group(S, reqs, free, overlap)
             if self._prefill_blocked:
                 # a fault just failed the prefill call and requeued its
                 # group; stop admitting this tick or we'd pick the same

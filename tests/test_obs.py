@@ -409,3 +409,90 @@ def test_fit_profile_from_engine_trace_matches_offered_traffic(setup):
     assert p.prompt_len[1] <= _PROFILE.prompt_len[1]
     assert p.deadline_slack == pytest.approx(3.0, abs=0.35)
     assert p.deadline_frac == 1.0
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; return the host spans it emitted
+    whose names look like program spans, as (start, end, name) sorted by
+    start."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            out += [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    for e in line.events
+                    if e.name.startswith(("engine.", "rnn."))]
+    return sorted(out)
+
+
+def test_span_names_are_unique_and_plain():
+    from repro.obs import SPANS
+
+    assert len(set(SPANS)) == len(SPANS) == 9
+    assert all(n.split(".")[0] in ("engine", "rnn") for n in SPANS)
+
+
+def test_engine_step_emits_its_phases_in_order(setup, tmp_path):
+    from repro.obs import SPANS
+
+    cfg, model, params, sharder = setup
+    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32)
+    eng.submit([1, 2, 3], max_new_tokens=3)
+    eng.run()                            # compiles prefill and decode
+    eng.submit([4, 5, 6, 7], max_new_tokens=3)
+    spans = _profiled(tmp_path, eng.step)
+    assert {n for _, _, n in spans} <= set(SPANS)
+    assert [n for _, _, n in spans] == [
+        "engine.step", "engine.schedule", "engine.prefill",
+        "engine.launch", "engine.readback", "engine.bookkeep"]
+    (s0, s1, _), (c0, c1, _), (p0, p1, _) = spans[:3]
+    assert s0 <= c0 <= p0 and p1 <= c1 <= s1       # step > schedule > prefill
+    phases = spans[3:]
+    assert c1 <= phases[0][0]
+    assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+    assert phases[-1][1] <= s1
+
+
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_rnn_serve_emits_plan_operands_launch(cell, tmp_path):
+    from repro.core.cells import RNNCellConfig, init_weights, \
+        quantize_weights
+    from repro.kernels.fused_rnn import ops
+    from repro.obs import SPANS
+
+    cfg = RNNCellConfig(cell, 128, timesteps=2, batch=1, precision="int8")
+    w = quantize_weights(cfg, init_weights(cfg, jax.random.PRNGKey(0)))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 1, cfg.d),
+                          jax.numpy.bfloat16)
+    ops.serve(cfg, w, x, interpret=True).block_until_ready()    # compile
+    spans = _profiled(tmp_path, lambda: ops.serve(
+        cfg, w, x, interpret=True).block_until_ready())
+    assert {n for _, _, n in spans} <= set(SPANS)
+    assert [n for _, _, n in spans] == ["rnn.plan", "rnn.operands",
+                                        "rnn.launch"]
+    assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+
+
+def test_jitted_programs_have_stable_names(setup):
+    cfg, model, params, sharder = setup
+    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32)
+    assert "decode_program" in eng.lower_decode().as_text()
+    assert eng._decode_many.__name__ == "decode_program"
+    assert eng._prefill.__name__ == "prefill_program"
