@@ -57,11 +57,13 @@ def kv_block_size(skv: int) -> int:
 
 def attention_specs(cfg: ModelConfig, prefix: str = "") -> Dict[str, ParamSpec]:
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
+    w = lambda shape, axes: ParamSpec(shape, jnp.float32, axes,
+                                      compute_only=True)
     specs = {
-        "wq": ParamSpec((d, qd), jnp.float32, ("embed", "q_flat")),
-        "wk": ParamSpec((d, kvd), jnp.float32, ("embed", "kv_flat")),
-        "wv": ParamSpec((d, kvd), jnp.float32, ("embed", "kv_flat")),
-        "wo": ParamSpec((qd, d), jnp.float32, ("q_flat", "embed")),
+        "wq": w((d, qd), ("embed", "q_flat")),
+        "wk": w((d, kvd), ("embed", "kv_flat")),
+        "wv": w((d, kvd), ("embed", "kv_flat")),
+        "wo": w((qd, d), ("q_flat", "embed")),
     }
     if cfg.qkv_bias:
         specs["bq"] = ParamSpec((qd,), jnp.float32, ("q_flat",), init="zeros")

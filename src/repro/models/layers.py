@@ -20,15 +20,22 @@ from repro.configs.base import ModelConfig
 from repro.models.params import ParamSpec
 
 F32 = jnp.float32
+# the activations' dtype (``embed``'s output), at which every matmul runs
+COMPUTE_DTYPE = jnp.bfloat16
 
 
 def wcast(w, dtype) -> jax.Array:
     """Weight view: plain array -> cast; int8-quantized dict -> dequantize.
 
     The paper's mixed-precision scheme at the XLA level: weights may be
-    *stored* int8 (HBM reads halve) and are widened right at the consuming
-    matmul, where XLA fuses the convert+scale into the operand so the wide
-    copy never materializes."""
+    *stored* int8 (HBM reads halve) and are widened at the consuming
+    matmul.  A convert inside a program is not free: on TPU v5e, XLA
+    hoists the convert of an f32 weight out of the layer scan and writes
+    the whole bf16 copy once per program call, at HBM speed, so that a
+    decode tick spent half its device time rounding weights.  The served
+    path therefore reads bf16 weights: ``ServingEngine`` rounds them once,
+    at construction (``LM.serving_params``), and the ``astype`` here is a
+    no-op on such a leaf."""
     if isinstance(w, dict):
         return (w["q"].astype(F32) * w["scale"].astype(F32)).astype(dtype)
     return w.astype(dtype)
@@ -135,11 +142,14 @@ _ACTS = {
 def mlp_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d, f = cfg.d_model, cfg.d_ff
     specs = {
-        "w_up": ParamSpec((d, f), jnp.float32, ("embed", "mlp")),
-        "w_down": ParamSpec((f, d), jnp.float32, ("mlp", "embed")),
+        "w_up": ParamSpec((d, f), jnp.float32, ("embed", "mlp"),
+                          compute_only=True),
+        "w_down": ParamSpec((f, d), jnp.float32, ("mlp", "embed"),
+                            compute_only=True),
     }
     if cfg.mlp_gated:
-        specs["w_gate"] = ParamSpec((d, f), jnp.float32, ("embed", "mlp"))
+        specs["w_gate"] = ParamSpec((d, f), jnp.float32, ("embed", "mlp"),
+                                    compute_only=True)
     return specs
 
 
@@ -164,14 +174,15 @@ def mlp(params: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig,
 def embed_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     v, d = cfg.padded_vocab, cfg.d_model
     specs = {"embedding": ParamSpec((v, d), jnp.float32, ("vocab", "embed"),
-                                    scale=1.0)}
+                                    scale=1.0, compute_only=True)}
     if not cfg.tie_embeddings:
-        specs["lm_head"] = ParamSpec((d, v), jnp.float32, ("embed", "vocab"))
+        specs["lm_head"] = ParamSpec((d, v), jnp.float32, ("embed", "vocab"),
+                                     compute_only=True)
     return specs
 
 
 def embed(params, tokens: jax.Array, cfg: ModelConfig, sharder) -> jax.Array:
-    x = params["embedding"].astype(jnp.bfloat16)[tokens]
+    x = params["embedding"].astype(COMPUTE_DTYPE)[tokens]
     if cfg.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
     return sharder.constrain(x, "batch", "seq", None)

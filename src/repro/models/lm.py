@@ -23,7 +23,7 @@ from repro.dist.sharding import Sharder
 from repro.models import params as pspec
 from repro.models.attention import cache_slot_count
 from repro.models.blocks import apply_block, attn_cache_entry, block_specs
-from repro.models.layers import embed, embed_specs, unembed
+from repro.models.layers import COMPUTE_DTYPE, embed, embed_specs, unembed
 from repro.models.params import ParamSpec
 from repro.models.ssm import _d_inner, _n_ssm_heads
 
@@ -32,6 +32,21 @@ F32 = jnp.float32
 
 def build_model(cfg: ModelConfig, tile_plans=None) -> "LM":
     return LM(cfg, tile_plans=tile_plans)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _serving_cast(xs, dtype):
+    """``xs`` cast to ``dtype`` in one program.  An elementwise convert
+    keeps its operand's sharding, and an uncommitted operand gives an
+    uncommitted result, free to follow the engine's programs."""
+    return [x.astype(dtype) for x in xs]
+
+
+def _wider_float(x, dtype) -> bool:
+    """``x`` is a plain floating array wider than ``dtype`` (an int8
+    ``{q, scale}`` dict is not)."""
+    return (hasattr(x, "dtype") and jnp.issubdtype(x.dtype, jnp.floating)
+            and jnp.dtype(x.dtype).itemsize > jnp.dtype(dtype).itemsize)
 
 
 class LM:
@@ -74,6 +89,32 @@ class LM:
 
     def n_params(self) -> int:
         return pspec.tree_size(self.param_specs())
+
+    def serving_params(self, params) -> Tuple[Any, int]:
+        """The copy of ``params`` that the serving programs read, and the
+        bytes of its ``compute_only`` leaves held at the compute dtype.
+
+        A leaf whose spec is ``compute_only`` and which is a plain floating
+        array wider than the compute dtype is rounded to it here, once, by
+        one jitted cast that keeps each leaf's sharding, instead of inside
+        every program call.  It is the rounding ``layers.wcast`` makes, so
+        the programs compute the same bf16 operands bit for bit.  Every
+        other leaf (read at f32, or an int8 ``{q, scale}`` dict, which
+        stays narrow) is passed through as stored.  Casting a copy again
+        changes nothing."""
+        flags = jax.tree.map(lambda s: s.compute_only, self.param_specs(),
+                             is_leaf=pspec.is_spec)
+        treedef = jax.tree.structure(flags)
+        subs = treedef.flatten_up_to(params)
+        marked = [i for i, f in enumerate(jax.tree.leaves(flags)) if f]
+        wide = [i for i in marked if _wider_float(subs[i], COMPUTE_DTYPE)]
+        if wide:
+            cast = _serving_cast([subs[i] for i in wide], COMPUTE_DTYPE)
+            for i, y in zip(wide, cast):
+                subs[i] = y
+        nbytes = sum(subs[i].nbytes for i in marked
+                     if getattr(subs[i], "dtype", None) == COMPUTE_DTYPE)
+        return treedef.unflatten(subs), int(nbytes)
 
     # ------------------------------------------------------------- period body
     def period_apply(self, p_params, x, *, positions=None, lengths=None,

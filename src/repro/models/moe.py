@@ -33,13 +33,14 @@ def moe_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
         "router": ParamSpec((d, m.n_experts), jnp.float32, ("embed", None),
                             scale=0.02),
         "w_up": ParamSpec((m.n_experts, d, f), jnp.float32,
-                          ("experts", "embed", "mlp")),
+                          ("experts", "embed", "mlp"), compute_only=True),
         "w_down": ParamSpec((m.n_experts, f, d), jnp.float32,
-                            ("experts", "mlp", "embed")),
+                            ("experts", "mlp", "embed"), compute_only=True),
     }
     if cfg.mlp_gated:
         specs["w_gate"] = ParamSpec((m.n_experts, d, f), jnp.float32,
-                                    ("experts", "embed", "mlp"))
+                                    ("experts", "embed", "mlp"),
+                                    compute_only=True)
     return specs
 
 

@@ -33,6 +33,10 @@ class ParamSpec:
     init: str = "normal"        # normal | zeros | ones | uniform | custom
     scale: Optional[float] = None
     custom_init: Optional[Callable[[jax.Array, "ParamSpec"], jax.Array]] = None
+    # the model reads this leaf only at the compute dtype (a matmul weight
+    # through layers.dot/wcast/embed/unembed or an MoE expert einsum), so a
+    # serving copy may hold it there: LM.serving_params
+    compute_only: bool = False
 
     def __post_init__(self):
         if self.axes and len(self.axes) != len(self.shape):

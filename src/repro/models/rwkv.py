@@ -31,25 +31,27 @@ def rwkv_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d = cfg.d_model
     ff = cfg.d_ff
     z = lambda *s: ParamSpec(tuple(s), jnp.float32, (None,) * len(s), init="zeros")
+    w = lambda shape, axes: ParamSpec(shape, jnp.float32, axes,
+                                      compute_only=True)
     specs = {
         "ln1": z(d),
         "ln2": z(d),
         # time-mix ddlerp
         "mu_base": z(d),
         "mu": z(N_MIX, d),
-        "lora_a": ParamSpec((d, N_MIX * LORA_RANK), jnp.float32, ("embed", None)),
+        "lora_a": w((d, N_MIX * LORA_RANK), ("embed", None)),
         "lora_b": ParamSpec((N_MIX, LORA_RANK, d), jnp.float32, (None, None, "embed"),
                             scale=1e-2),
         # projections
-        "wr": ParamSpec((d, d), jnp.float32, ("embed", "q_flat")),
-        "wk": ParamSpec((d, d), jnp.float32, ("embed", "q_flat")),
-        "wv": ParamSpec((d, d), jnp.float32, ("embed", "q_flat")),
-        "wg": ParamSpec((d, d), jnp.float32, ("embed", "q_flat")),
-        "wo": ParamSpec((d, d), jnp.float32, ("q_flat", "embed")),
+        "wr": w((d, d), ("embed", "q_flat")),
+        "wk": w((d, d), ("embed", "q_flat")),
+        "wv": w((d, d), ("embed", "q_flat")),
+        "wg": w((d, d), ("embed", "q_flat")),
+        "wo": w((d, d), ("q_flat", "embed")),
         # data-dependent decay
         "decay_base": ParamSpec((d,), jnp.float32, (None,), init="custom",
                                 custom_init=_decay_init),
-        "decay_a": ParamSpec((d, DECAY_RANK), jnp.float32, ("embed", None)),
+        "decay_a": w((d, DECAY_RANK), ("embed", None)),
         "decay_b": ParamSpec((DECAY_RANK, d), jnp.float32, (None, "embed"),
                              scale=1e-2),
         "bonus": z(d),
@@ -57,9 +59,9 @@ def rwkv_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
         # channel mix
         "mu_ck": z(d),
         "mu_cr": z(d),
-        "wk_c": ParamSpec((d, ff), jnp.float32, ("embed", "mlp")),
-        "wv_c": ParamSpec((ff, d), jnp.float32, ("mlp", "embed")),
-        "wr_c": ParamSpec((d, d), jnp.float32, ("embed", "q_flat")),
+        "wk_c": w((d, ff), ("embed", "mlp")),
+        "wv_c": w((ff, d), ("mlp", "embed")),
+        "wr_c": w((d, d), ("embed", "q_flat")),
     }
     return specs
 

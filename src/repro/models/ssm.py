@@ -35,11 +35,13 @@ def ssm_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d, s = cfg.d_model, cfg.ssm
     di, nh = _d_inner(cfg), _n_ssm_heads(cfg)
     return {
-        "w_in": ParamSpec((d, 2 * di), jnp.float32, ("embed", "ssm_inner")),
+        "w_in": ParamSpec((d, 2 * di), jnp.float32, ("embed", "ssm_inner"),
+                          compute_only=True),
         "conv_kernel": ParamSpec((s.conv_width, di), jnp.float32,
                                  (None, "ssm_inner"), scale=0.5),
         "conv_bias": ParamSpec((di,), jnp.float32, ("ssm_inner",), init="zeros"),
-        "w_bc": ParamSpec((di, 2 * s.d_state), jnp.float32, ("ssm_inner", None)),
+        "w_bc": ParamSpec((di, 2 * s.d_state), jnp.float32, ("ssm_inner", None),
+                          compute_only=True),
         "w_dt": ParamSpec((d, nh), jnp.float32, ("embed", None)),
         "dt_bias": ParamSpec((nh,), jnp.float32, (None,), init="custom",
                              custom_init=_dt_bias_init),
@@ -47,7 +49,8 @@ def ssm_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
                            custom_init=_a_log_init),
         "d_skip": ParamSpec((nh,), jnp.float32, (None,), init="ones"),
         "ssm_norm": ParamSpec((di,), jnp.float32, ("ssm_inner",), init="zeros"),
-        "w_out": ParamSpec((di, d), jnp.float32, ("ssm_inner", "embed")),
+        "w_out": ParamSpec((di, d), jnp.float32, ("ssm_inner", "embed"),
+                           compute_only=True),
     }
 
 

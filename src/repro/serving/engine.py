@@ -258,7 +258,9 @@ class ServingEngine:
             model = model.with_tile_plans(plan.tile_plans)
         self.plan = plan
         self.model = model
-        self.params = params
+        # the programs read the serving copy: weights rounded to the compute
+        # dtype once here, not converted in every decode and prefill call
+        self.params, cast_bytes = model.serving_params(params)
         self.sharder = sharder
         self.max_batch = plan.max_batch
         self.max_len = plan.max_len
@@ -319,6 +321,11 @@ class ServingEngine:
                                "stuck slots evicted by the watchdog")
         self.metrics.gauge("engine.ticks", "virtual-clock tick counter",
                            fn=lambda: float(self._tick))
+        self.metrics.gauge("engine.params_cast_bytes",
+                           "parameter bytes the serving copy holds at the "
+                           "compute dtype", fn=lambda: float(cast_bytes))
+        log.info("serving copy: %d parameter bytes at the compute dtype",
+                 cast_bytes)
         self.finished: List[Request] = []   # completed Requests, in order
         self.util_history: List[float] = []  # per-tick (active+instant)/max
         self.prefill_shapes: Set[Tuple[int, int]] = set()  # (rows, S) seen

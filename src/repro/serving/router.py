@@ -235,7 +235,10 @@ class Router:
                 cfg = (reduced_config(plan.arch) if plan.reduced
                        else get_config(plan.arch))
                 model = build_model(cfg)
-                built[key] = (model, model.init(jax.random.PRNGKey(0)))
+                # the replicas share one serving copy of the weights
+                params, _ = model.serving_params(
+                    model.init(jax.random.PRNGKey(0)))
+                built[key] = (model, params)
             model, params = built[key]
             eng = ServingEngine.from_plan(
                 plan, params, model=model, seed=seed + i,

@@ -9,9 +9,13 @@ so the smoke tests exercise the same code paths the full configs lower.
 from __future__ import annotations
 
 import dataclasses
+from typing import Set, Tuple
+
+import jax
 
 from repro.configs import get_config
 from repro.configs.base import ModelConfig, MoEConfig, RWKVConfig, SSMConfig, ShapeSpec
+from repro.models.params import is_spec
 
 
 def reduced_config(arch: str, **overrides) -> ModelConfig:
@@ -59,3 +63,15 @@ def reduced_config(arch: str, **overrides) -> ModelConfig:
 
 def smoke_shape(mode: str = "train", seq: int = 16, batch: int = 2) -> ShapeSpec:
     return ShapeSpec(f"smoke_{mode}", seq, batch, mode)
+
+
+def compute_weight_shapes(model) -> Set[Tuple[int, ...]]:
+    """The shapes a ``compute_only`` weight of ``model`` takes in a program:
+    the whole leaf and, for a layer-stacked leaf, one scan step's slice."""
+    out: Set[Tuple[int, ...]] = set()
+    for s in jax.tree.leaves(model.param_specs(), is_leaf=is_spec):
+        if s.compute_only:
+            out.add(s.shape)
+            if s.axes[:1] == ("layers",):
+                out |= {s.shape[1:], (1,) + s.shape[1:]}
+    return out

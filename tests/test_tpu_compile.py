@@ -11,6 +11,8 @@ import: only one process may load the TPU library at a time, and every
 test worker imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -163,3 +165,45 @@ def test_matmul_w8a16_qwen2_5_14b_mlp(one_chip):
              lambda x, w, s: matmul_w8a16(x, w, s, bm=p.bm, bn=p.bn,
                                           bk=p.bk),
              ((M, K), BF16), ((K, N), I8), ((N,), F32))
+
+
+def _weight_converts(hlo: str, shapes) -> set:
+    """The shapes among ``shapes`` of the bf16 arrays that ``convert``s of
+    compiled HLO text round from an f32 operand."""
+    types = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo))
+    out = set()
+    for dims, operand in re.findall(
+            r"= bf16\[([\d,]*)\]\S* convert\(%([\w.\-]+)\)", hlo):
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        if types.get(operand, "").startswith("f32[") and shape in shapes:
+            out.add(shape)
+    return out
+
+
+def test_rwkv6_decode_reads_the_serving_copy(one_chip):
+    """Compiled for a v5e, a decode step over the serving copy converts no
+    weight, where over the f32 masters XLA writes a bf16 copy of every
+    weight stack on every call (a reduced rwkv6: the mechanism, not the
+    size)."""
+    from repro.dist.sharding import Sharder
+    from repro.models.lm import build_model
+    from repro.models.params import is_spec, tree_abstract
+    from repro.testing import compute_weight_shapes, reduced_config
+
+    model = build_model(reduced_config("rwkv6-1.6b", d_model=128))
+    params = model.init(jax.random.PRNGKey(0))
+    copy, _ = model.serving_params(params)
+    at = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    cache = at(tree_abstract(model.cache_specs(8, 64)))
+    tokens = jax.ShapeDtypeStruct((8,), I32, sharding=one_chip)
+    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t,
+                                                     Sharder(None, {})))
+    weights = compute_weight_shapes(model)
+    served = step.lower(at(copy), cache, tokens).compile().as_text()
+    masters = step.lower(at(params), cache, tokens).compile().as_text()
+    assert _weight_converts(served, weights) == set()
+    assert _weight_converts(masters, weights) == {
+        s.shape for s in jax.tree.leaves(model.param_specs(), is_leaf=is_spec)
+        if s.compute_only}
