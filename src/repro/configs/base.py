@@ -151,6 +151,10 @@ class ModelConfig:
     # 8-bit storage/multiply, wider accumulate).
     serve_int8: bool = False
     kv_cache_dtype: str = "bf16"        # "bf16" | "int8"
+    # storage dtype of the weights the model reads only at the compute
+    # dtype (``ParamSpec.compute_only``: matmul weights, embedding, head);
+    # norms, biases and other f32-read leaves stay f32 whatever it says
+    weight_dtype: str = "float32"       # "float32" | "bfloat16"
 
     # ------------------------------------------------------------------ derived
     @property

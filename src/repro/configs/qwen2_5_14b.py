@@ -1,6 +1,9 @@
 """Qwen2.5-14B  [dense]  48L d_model=5120 40H (GQA kv=8) d_ff=13824
-vocab=152064 — GQA, QKV bias.  [hf:Qwen/Qwen2.5-0.5B; hf]
+vocab=152064 — GQA, QKV bias, RoPE theta 1e6, RMSNorm eps 1e-5, untied head.
+[arXiv:2412.15115; hf:Qwen/Qwen2.5-14B config.json]
 
+The matmul weights are stored at bf16, the checkpoint's ``torch_dtype``
+(29.5 GB in all, 7.4 GB a chip over four); norms and biases stay f32.
 40 query heads do not divide the 16-way model axis, so attention activations
 are sequence-sharded ("qseq") while the projection weights stay flat-sharded
 (5120 / 1024 both divide 16).  14.8B params require FSDP at train_4k.
@@ -20,6 +23,8 @@ CONFIG = ModelConfig(
     vocab_size=152064,
     qkv_bias=True,
     rope_theta=1e6,
+    norm_eps=1e-5,
+    weight_dtype="bfloat16",
     layer_pattern=("attn",),
     fsdp=True,
     remat="full",

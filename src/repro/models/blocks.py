@@ -132,6 +132,12 @@ def _attn_seq(params, x, cfg: ModelConfig, sharder, positions, *,
     if mode == "prefill":
         n_slots = min(window, max_len or S) if window else (max_len or S)
         kc, vc, pc = attn.fill_cache_from_prefill(k, v, pos2d, n_slots)
+        # the cache leaves the program laid out as decode reads it (see
+        # attn_cache_entry), not replicated on every device
+        seq_ax = "window" if n_slots < (max_len or S) else "cache_seq"
+        kc = sharder.constrain(kc, "batch", seq_ax, "kv_heads", None)
+        vc = sharder.constrain(vc, "batch", seq_ax, "kv_heads", None)
+        pc = sharder.constrain(pc, "batch", seq_ax)
         entry = _encode_kv(cfg, kc, vc)
         entry["pos"] = pc.astype(jnp.int32)
     return out, entry

@@ -12,6 +12,7 @@ scanned body separately and scale its cost by the trip count
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -40,6 +41,18 @@ def _serving_cast(xs, dtype):
     keeps its operand's sharding, and an uncommitted operand gives an
     uncommitted result, free to follow the engine's programs."""
     return [x.astype(dtype) for x in xs]
+
+
+def _store_compute_only(specs, dtype):
+    """``specs`` with every ``compute_only`` f32 leaf stored at ``dtype``
+    (``ModelConfig.weight_dtype``); the model reads such a leaf only at
+    the compute dtype, so a bf16 store loses nothing the programs use."""
+    if dtype == F32:
+        return specs
+    return jax.tree.map(
+        lambda s: dataclasses.replace(s, dtype=dtype)
+        if s.compute_only and s.dtype == F32 else s,
+        specs, is_leaf=pspec.is_spec)
 
 
 def _wider_float(x, dtype) -> bool:
@@ -79,7 +92,7 @@ class LM:
                 enc_period, cfg.n_encoder_layers)
             specs["enc_final_norm"] = ParamSpec((cfg.d_model,), F32, (None,),
                                                 init="zeros")
-        return specs
+        return _store_compute_only(specs, jnp.dtype(cfg.weight_dtype))
 
     def init(self, key: jax.Array):
         return pspec.tree_init(self.param_specs(), key)
@@ -275,9 +288,15 @@ class LM:
             period[key] = entry
         return period
 
-    def init_cache(self, batch: int, max_len: int):
-        return pspec.tree_init(self.cache_specs(batch, max_len),
-                               jax.random.PRNGKey(0))
+    def init_cache(self, batch: int, max_len: int, shardings=None):
+        """The empty serving cache; with ``shardings`` (a tree from
+        ``Sharder.param_shardings`` of :meth:`cache_specs`) it is made
+        in place, each device writing only its own shard."""
+        specs = self.cache_specs(batch, max_len)
+        if shardings is None:
+            return pspec.tree_init(specs, jax.random.PRNGKey(0))
+        return jax.jit(functools.partial(pspec.tree_init, specs),
+                       out_shardings=shardings)(jax.random.PRNGKey(0))
 
     def cache_batch_axes(self, cache) -> Dict[str, Any]:
         """Batch(=slot)-axis index for every cache leaf — the cache pytree

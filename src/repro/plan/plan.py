@@ -40,6 +40,11 @@ import dataclasses
 from typing import Dict, List, Mapping, Optional, Tuple
 
 MIN_BUCKET = 8   # smallest prefill length bucket (pow2 upward, cap max_len-1)
+# padded prompt tokens one bucketed prefill call holds (rows x bucket),
+# beyond one row: bounds a call's activations and cache output, and the
+# padding rows a lone admission pays for (24 rows of a 2048 bucket would
+# be a 4.8 GB cache output a chip for qwen2.5-14b over four chips)
+PREFILL_TOKENS = 1024
 
 
 def parse_cache_layout(layout: str) -> Optional[int]:
@@ -291,6 +296,12 @@ class ServingPlan:
         return self
 
     # ------------------------------------------------------------ resolution
+    def prefill_rows(self, bucket: int) -> int:
+        """Rows of one bucketed prefill call at ``bucket`` tokens: as
+        many as ``PREFILL_TOKENS`` holds, at least one and at most
+        ``max_batch``."""
+        return max(1, min(self.max_batch, PREFILL_TOKENS // bucket))
+
     def resolved_buckets(self) -> Tuple[int, ...]:
         """The explicit bucket set this plan serves with (the pow2 default
         when ``buckets`` is None).  In non-bucketed mode prefill pads to

@@ -159,6 +159,25 @@ def _is_reduced(cfg) -> bool:
         return True
 
 
+def _kv_cache_bytes(model: LM, sharder: Sharder, max_batch: int,
+                    max_len: int) -> int:
+    """Bytes of the dense cache's KV rings (``LM.PAGEABLE_LEAVES``: k, v,
+    their positions and int8 scales) that one device holds under
+    ``sharder``; 0 for a model with no attention cache."""
+    from repro.models.params import is_spec
+
+    total = 0
+    for path, s in jax.tree_util.tree_leaves_with_path(
+            model.cache_specs(max_batch, max_len), is_leaf=is_spec):
+        if getattr(path[-1], "key", None) not in LM.PAGEABLE_LEAVES:
+            continue
+        shape = s.shape
+        if sharder.mesh is not None:
+            shape = sharder.sharding(s.axes, s.shape).shard_shape(s.shape)
+        total += int(np.prod(shape)) * np.dtype(s.dtype).itemsize
+    return total
+
+
 @dataclasses.dataclass
 class _PendingAdmit:
     """An overlapped admission group: first tokens still on device, host
@@ -282,7 +301,7 @@ class ServingEngine:
         self._paged = plan.cache_layout != "dense"
         self.sm = make_slot_manager(model, self.max_batch, self.max_len,
                                     layout=plan.cache_layout,
-                                    registry=self.metrics)
+                                    registry=self.metrics, sharder=sharder)
         c = self.metrics.counter
         self._c_completed = c("engine.completed",
                               "requests finished since construction")
@@ -304,6 +323,11 @@ class ServingEngine:
                                    "tokens already generated at eviction")
         self._c_shed = c("engine.shed",
                          "requests rejected at submit (admission control)")
+        self._c_kv_tokens = c("engine.kv_tokens",
+                              "cache positions the decode ticks attended "
+                              "over, summed over slots and ticks")
+        self._c_prefill_tokens = c("engine.prefill_tokens",
+                                   "prompt tokens prefilled")
         # fault-tolerance counters: registered always (so reset_telemetry
         # covers them), but surfaced via fault_stats() rather than stats()
         # — no-fault runs keep their historical stats()/BENCH bytes
@@ -326,8 +350,20 @@ class ServingEngine:
                            "compute dtype", fn=lambda: float(cast_bytes))
         log.info("serving copy: %d parameter bytes at the compute dtype",
                  cast_bytes)
+        kv_bytes = _kv_cache_bytes(model, sharder, self.max_batch,
+                                   self.max_len)
+        self.metrics.gauge("engine.kv_cache_bytes",
+                           "bytes of the KV cache rings one device holds",
+                           fn=lambda: float(kv_bytes))
+        log.info("kv cache: %d bytes a device", kv_bytes)
         self.finished: List[Request] = []   # completed Requests, in order
         self.util_history: List[float] = []  # per-tick (active+instant)/max
+        # per tick, beside util_history: the cache positions its decode
+        # attended over (engine.kv_tokens), and the prompt lengths
+        # prefilled just before it (engine.prefill_tokens)
+        self.kv_history: List[int] = []
+        self.prefill_history: List[Tuple[int, ...]] = []
+        self._tick_prefill: Tuple[int, ...] = ()
         self.prefill_shapes: Set[Tuple[int, int]] = set()  # (rows, S) seen
         self.tracer = tracer          # optional structured event tracer
         self.live: Optional[LiveMetrics] = None   # enable_live_metrics()
@@ -668,12 +704,16 @@ class ServingEngine:
         if self.tracer is not None:
             self.tracer.decode_chunk(base, n, len(active_idx))
         for j in range(n):
-            n_active = 0
+            n_active = kv = 0
             for i in active_idx:
                 req = self.sm.slots[i]
                 if req is None or not acts[j, i] or i in bad_set:
                     continue
                 n_active += 1
+                # the tick fed the last served token at position
+                # len(prompt) + len(output) - 1 and attended over it and
+                # every position before it, up to the ring's length
+                kv += min(len(req.prompt) + len(req.output), self.max_len)
                 progressed.add(i)
                 req.output.append(int(toks[j, i]))
                 self._c_total_tokens.inc()
@@ -682,7 +722,8 @@ class ServingEngine:
                     self.sm.release(i)
             self._observe_tick(
                 base + j,
-                (n_active + (n_instant if j == 0 else 0)) / self.max_batch)
+                (n_active + (n_instant if j == 0 else 0)) / self.max_batch,
+                kv)
         self._tick += n
         if self.tracer is not None:
             self.tracer.host_sync(self._tick)
@@ -711,11 +752,17 @@ class ServingEngine:
         if self.live is not None:
             self.live.observe_request(req, tick)
 
-    def _observe_tick(self, tick: int, util: float) -> None:
+    def _observe_tick(self, tick: int, util: float, kv: int = 0) -> None:
         """One virtual-clock tick's utilization, fanned out to every
         observer: the aggregate history, the rolling live window, and the
-        trace's counter track."""
+        trace's counter track; beside it the tick's attended cache
+        positions ``kv`` and the prompt lengths prefilled since the last
+        tick."""
         self.util_history.append(util)
+        self.kv_history.append(kv)
+        self._c_kv_tokens.inc(kv)
+        self.prefill_history.append(self._tick_prefill)
+        self._tick_prefill = ()
         if self.live is not None:
             self.live.observe_tick(tick, util)
         if self.tracer is not None:
@@ -969,10 +1016,20 @@ class ServingEngine:
         the prefill program and never came to host)."""
         if not self._pending:
             return self.sm.next_token
-        tokens = jnp.asarray(self.sm.next_token)
+        # placed whole where the first tokens are, so that every merge
+        # below sees the same placements, whichever admission it follows
+        place = self._pending[0].first.sharding
+        if not place.is_fully_replicated:
+            place = jax.sharding.NamedSharding(place.mesh,
+                                               jax.sharding.PartitionSpec())
+        tokens = jax.device_put(self.sm.next_token, place)
         for p in self._pending:
-            tokens = tokens.at[jnp.asarray(p.slots, jnp.int32)].set(
-                p.first[jnp.asarray(p.rows, jnp.int32)])
+            # every prefill row scatters, rows with no slot past the last
+            # and dropped: the op's shapes follow the prefill's row count
+            target = np.full(p.first.shape, self.max_batch, np.int32)
+            target[p.rows] = p.slots
+            tokens = tokens.at[jnp.asarray(target)].set(p.first,
+                                                        mode="drop")
         return tokens
 
     # ----------------------------------------------------------- scheduling
@@ -1083,8 +1140,13 @@ class ServingEngine:
                        and not any(r.eos_id is not None
                                    or r.max_new_tokens == 1 for r in fresh))
             for S, reqs in grouped:
-                with span("engine.prefill"):
-                    n_instant += self._prefill_group(S, reqs, free, overlap)
+                # a bucket's calls hold at most plan.prefill_rows(S) rows
+                step = (self.plan.prefill_rows(S) if self.bucketed_prefill
+                        else len(reqs))
+                for i in range(0, len(reqs), step):
+                    with span("engine.prefill"):
+                        n_instant += self._prefill_group(
+                            S, reqs[i:i + step], free, overlap)
             if self._prefill_blocked:
                 # a fault just failed the prefill call and requeued its
                 # group; stop admitting this tick or we'd pick the same
@@ -1117,7 +1179,8 @@ class ServingEngine:
             for req in reqs:
                 self._rollback(req, self._tick, "fail_prefill")
             return 0
-        rows = self.max_batch if self.bucketed_prefill else len(reqs)
+        rows = self.plan.prefill_rows(S) if self.bucketed_prefill \
+            else len(reqs)
         tokens = np.zeros((rows, S), np.int32)
         lengths = np.ones((rows,), np.int32)   # dummy rows: 1 valid token
         for r_i, req in enumerate(reqs):
@@ -1134,6 +1197,9 @@ class ServingEngine:
             self.tracer.prefill(self._tick, S, rows, len(reqs), overlap)
         cacheN, logitsN = self._prefill(self.params, batch)
         self._c_prefill_calls.inc()
+        real = tuple(min(len(r.prompt), S) for r in reqs)
+        self._c_prefill_tokens.inc(sum(real))
+        self._tick_prefill += real
         self.prefill_shapes.add((rows, S))
         self._key, first = split_and_sample(self._key, logitsN, self.sampler)
         if overlap:
@@ -1243,6 +1309,8 @@ class ServingEngine:
             "stalled": sorted(self._stalled),
             "last_progress": [int(x) for x in self._last_progress],
             "util_history": list(self.util_history),
+            "kv_history": list(self.kv_history),
+            "prefill_history": [list(n) for n in self.prefill_history],
             "counters": {
                 "total_tokens": self.total_tokens,
                 "instant_admits": self.instant_admits,
@@ -1335,6 +1403,11 @@ class ServingEngine:
         eng._tick = int(ex["tick"])
         eng._uid_next = int(ex["uid_next"])
         eng.util_history = list(ex.get("util_history", []))
+        eng.kv_history = list(ex.get("kv_history",
+                                     [0] * len(eng.util_history)))
+        eng.prefill_history = [
+            tuple(int(x) for x in n) for n in ex.get(
+                "prefill_history", [[]] * len(eng.util_history))]
         eng._stalled = set(int(s) for s in ex.get("stalled", []))
         eng._last_progress[:] = np.asarray(ex["last_progress"],
                                            dtype=np.int64)
@@ -1374,6 +1447,9 @@ class ServingEngine:
         self.metrics.reset()
         self.finished = []
         self.util_history = []
+        self.kv_history = []
+        self.prefill_history = []
+        self._tick_prefill = ()
         self._tick = 0
         if self.live is not None:
             self.live.reset()

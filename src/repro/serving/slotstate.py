@@ -31,6 +31,7 @@ noise, not state corruption).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -56,6 +57,15 @@ def gather_slots(cache, axes, slots: Sequence[int]):
     idx = jnp.asarray(list(slots), jnp.int32)
     return jax.tree.map(lambda a, ax: jnp.take(a, idx, axis=ax),
                         cache, axes)
+
+
+def _scatter_rows(cache, sub, slots, *, axes):
+    """Row ``r`` of ``sub`` into slot ``slots[r]`` of ``cache``, every
+    leaf at once; a slot index past the last slot drops its row."""
+    return jax.tree.map(
+        lambda a, s, ax: a.at[_index(a, ax, slots)].set(
+            s.astype(a.dtype), mode="drop"),
+        cache, sub, axes)
 
 
 def scatter_slots(cache, axes, slots: Sequence[int], sub):
@@ -95,10 +105,20 @@ class SlotManager:
     gets a slot — stays in :mod:`repro.serving.scheduler`."""
 
     def __init__(self, model: LM, max_batch: int, max_len: int,
-                 registry: Optional[MetricsRegistry] = None):
+                 registry: Optional[MetricsRegistry] = None,
+                 sharder=None):
         self.max_batch = max_batch
         self.max_len = max_len
+        # a mesh sharder lays the cache out as the decode program reads it
+        # (KV rings split along their length); None keeps one device
+        self._cache_shardings = (
+            sharder.param_shardings(model.cache_specs(max_batch, max_len))
+            if sharder is not None and sharder.mesh is not None else None)
         self._init_storage(model, max_batch, max_len)
+        # prefill rows scatter in place: one program per prefill row count
+        self._insert_rows = jax.jit(
+            functools.partial(_scatter_rows, axes=self.axes),
+            donate_argnums=0)
         self._init_byte_accounting(model)
         self._init_col_specs(model)
         self.slots: List[Optional[object]] = [None] * max_batch
@@ -143,7 +163,8 @@ class SlotManager:
         pytree directly; :class:`repro.serving.paged.PagedSlotManager`
         overrides this to build block pools instead and serves ``cache``
         as a materialized view property."""
-        self.cache = model.init_cache(max_batch, max_len)
+        self.cache = model.init_cache(max_batch, max_len,
+                                      self._cache_shardings)
         self.axes = model.cache_batch_axes(self.cache)
         self.page_axes = model.cache_page_axes(self.cache)
 
@@ -313,12 +334,14 @@ class SlotManager:
         the whole admitted group): the write half of the gather/scatter
         pair, with the prefill batch rows as the source columns."""
         self._prefill_inserts.inc(len(list(slots)))
-        sl = jnp.asarray(list(slots), jnp.int32)
-        rw = jnp.asarray(list(rows), jnp.int32)
-        self.cache = jax.tree.map(
-            lambda big, small, ax: big.at[_index(big, ax, sl)].set(
-                jnp.take(small, rw, axis=ax).astype(big.dtype)),
-            self.cache, cacheN, self.axes)
+        # every row of cacheN scatters; rows no slot was granted to aim
+        # past the last slot and drop, so the program depends only on the
+        # prefill's row count, not on how many requests it admitted
+        target = np.full((int(cacheN["lengths"].shape[0]),), self.max_batch,
+                         np.int32)
+        target[list(rows)] = list(slots)
+        self.cache = self._insert_rows(self.cache, cacheN,
+                                       jnp.asarray(target))
 
     # ------------------------------------------------------ preempt / resume
     def snapshot(self, slot: int) -> SlotSnapshot:
@@ -406,17 +429,19 @@ class SlotManager:
 
 def make_slot_manager(model: LM, max_batch: int, max_len: int, *,
                       layout: str = "dense",
-                      registry: Optional[MetricsRegistry] = None
-                      ) -> SlotManager:
+                      registry: Optional[MetricsRegistry] = None,
+                      sharder=None) -> SlotManager:
     """Construct the slot manager for a ``ServingPlan.cache_layout``:
     ``"dense"`` → :class:`SlotManager`, ``"paged:<block_size>"`` →
     :class:`repro.serving.paged.PagedSlotManager` (imported lazily; it
-    depends on this module)."""
+    depends on this module).  A mesh ``sharder`` lays the dense cache out
+    on its mesh; the paged pool stays on one device."""
     from repro.plan.plan import parse_cache_layout
 
     block = parse_cache_layout(layout)
     if block is None:
-        return SlotManager(model, max_batch, max_len, registry=registry)
+        return SlotManager(model, max_batch, max_len, registry=registry,
+                           sharder=sharder)
     from repro.serving.paged import PagedSlotManager
 
     return PagedSlotManager(model, max_batch, max_len, block_size=block,

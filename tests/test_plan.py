@@ -443,3 +443,17 @@ def test_run_cell_embeds_resolved_plan():
                              workload=cell.workload)
     again = sl.run_cell(recell, duration=8.0, seed=0)
     assert again["metrics"] == out["metrics"]
+
+
+@pytest.mark.parametrize("max_batch,bucket,rows", [
+    (16, 64, 16), (24, 256, 4), (24, 512, 2), (24, 1024, 1), (24, 2048, 1),
+    (2, 8, 2)])
+def test_prefill_rows_hold_the_token_budget(max_batch, bucket, rows):
+    """A bucketed prefill call holds PREFILL_TOKENS padded tokens, at least
+    one row and at most max_batch: short buckets keep max_batch rows,
+    long ones take fewer."""
+    from repro.plan.plan import PREFILL_TOKENS
+
+    plan = ServingPlan(arch=ARCH, max_batch=max_batch, max_len=4096)
+    assert PREFILL_TOKENS == 1024
+    assert plan.prefill_rows(bucket) == rows

@@ -207,3 +207,84 @@ def test_rwkv6_decode_reads_the_serving_copy(one_chip):
     assert _weight_converts(masters, weights) == {
         s.shape for s in jax.tree.leaves(model.param_specs(), is_leaf=is_spec)
         if s.compute_only}
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo, one_chip):
+    """A 1 x 4 mesh of the described v5e:2x2's chips (compile cache off,
+    as for ``one_chip``)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(topo.devices[:4]).reshape(1, 4),
+                ("data", "model"))
+
+
+def _per_chip_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_qwen2_5_14b_chat_tp4_programs(four_chips, monkeypatch):
+    """The chat-tp4 cell's two programs at full depth and published
+    widths, tensor-parallel over a described v5e:2x2 at the cell's plan
+    (24 slots, a 4096-token cache, 1024-token prefill calls): the decode
+    program holds ``flash_decode``, a prefill of the 2048 bucket holds
+    ``flash_attention``, and each chip's parameters, cache and
+    temporaries fit its 16 GB."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.dist.sharding import make_sharder
+    from repro.kernels import dispatch
+    from repro.models.lm import build_model
+    from repro.models.params import tree_abstract
+    from repro.plan import ServingPlan
+    from repro.serving.engine import _decode_many
+    from repro.serving.sampler import SamplerConfig
+
+    from repro.kernels.flash_attention import ops as flash_ops
+
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    # the flash adapters hold their own reference to interpret_mode
+    monkeypatch.setattr(flash_ops, "interpret_mode", lambda: False)
+    plan = ServingPlan(arch="qwen2.5-14b", reduced=False, max_batch=24,
+                       max_len=4096)
+    cfg = get_config(plan.arch)
+    model = build_model(cfg, tile_plans=planner.tile_plans_for(
+        plan.arch, plan.max_batch, hw.TPU_V5E, max_len=plan.max_len))
+    sharder = make_sharder(cfg, four_chips, plan.shard_mode)
+
+    def placed(specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree_abstract(specs), sharder.param_shardings(specs))
+
+    rep = NamedSharding(four_chips, P())
+    at = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+    params = placed(model.param_specs())
+    B, L = plan.max_batch, plan.max_len
+    cache = placed(model.cache_specs(B, L))
+
+    def decode(params, cache, tokens, key, active, eos, remaining, limit,
+               stop):
+        return _decode_many(model, sharder, SamplerConfig(), L, 1, params,
+                            cache, tokens, key, active, eos, remaining,
+                            limit, stop)
+
+    dec = jax.jit(decode, donate_argnums=1).lower(
+        params, cache, at((B,), I32), at((2,), jnp.uint32), at((B,), bool),
+        at((B,), I32), at((B,), I32), at((), I32), at((), bool)).compile()
+    rows = plan.prefill_rows(2048)
+    pre = jax.jit(lambda p, b: model.prefill(p, b, sharder, max_len=L)
+                  ).lower(params, {"tokens": at((rows, 2048), I32),
+                                   "lengths": at((rows,), I32)}).compile()
+    assert re.search(r"%flash_decode[.\d]* = .*tpu_custom_call", dec.as_text())
+    assert re.search(r"%flash_attention\w*[.\d]* = .*tpu_custom_call",
+                     pre.as_text())
+    cache_bytes = sum(s.size * jnp.dtype(s.dtype).itemsize // 4
+                      for s in jax.tree.leaves(cache))
+    assert _per_chip_bytes(dec) < 16e9
+    # a prefill runs beside the engine's cache
+    assert _per_chip_bytes(pre) + cache_bytes < 16e9
