@@ -169,7 +169,7 @@ def build_train_cell(model: LM, shape: ShapeSpec, mesh, sharder: Sharder,
         enc_abs = _abstract_x(cfg, B_micro, S // cfg.encoder_downsample)
 
     def period_loss(p_params, x, enc_out=None):
-        y, _, aux = model.period_apply(
+        y, _, aux, _ = model.period_apply(
             p_params, x, positions=positions, mode="train", sharder=sharder,
             enc_out=enc_out)
         if cfg.shard_residual_seq:
@@ -231,7 +231,7 @@ def build_train_cell(model: LM, shape: ShapeSpec, mesh, sharder: Sharder,
         pos_e = _positions(cfg, B_micro, Se)
 
         def enc_loss(p_params, x):
-            y, _, aux = model.period_apply(
+            y, _, aux, _ = model.period_apply(
                 p_params, x, positions=pos_e, mode="train", sharder=sharder,
                 causal=False)
             return jnp.sum(y.astype(F32)) * 1e-6 + aux
@@ -276,7 +276,7 @@ def build_serve_cell(model: LM, shape: ShapeSpec, mesh, sharder: Sharder,
                        if cfg.is_encoder_decoder else None)
 
             def period_fwd(p_params, x, enc_out=None):
-                y, cache, _ = model.period_apply(
+                y, cache, _, _ = model.period_apply(
                     p_params, x, positions=positions, mode="prefill",
                     sharder=sharder, enc_out=enc_out, max_len=S)
                 return y, cache
@@ -333,7 +333,7 @@ def build_serve_cell(model: LM, shape: ShapeSpec, mesh, sharder: Sharder,
                      else jnp.broadcast_to(lengths[:, None, None], (B, 3, 1)))
 
         def period_step(p_params, x, p_cache):
-            y, new_c, _ = model.period_apply(
+            y, new_c, _, _ = model.period_apply(
                 p_params, x, positions=positions, lengths=lengths,
                 mode="decode", sharder=sharder, p_cache=p_cache)
             return y, new_c

@@ -21,7 +21,9 @@ Design notes (see DESIGN.md §Attention):
   over the model axis (flash-decode style): the softmax over the sharded
   dim lowers to partial max/sum + all-reduce, and the A·V contraction to a
   partial-sum all-reduce.  This works for every head count, so decode needs
-  no head-divisibility at all.
+  no head-divisibility at all.  The cache is stored (layers, B, S, K, hd);
+  the decode layer scan (``LM._scan``) carries those stacks and writes one
+  row per slot a tick into them, never a whole layer.
 """
 
 from __future__ import annotations
@@ -281,19 +283,6 @@ def cache_slot_count(cfg: ModelConfig, kind: str, max_len: int) -> int:
     return max_len
 
 
-def update_cache(k_cache, v_cache, kv_pos, k_new, v_new, lengths, *,
-                 n_slots: int, ring: bool):
-    """Insert one token per sequence.  k_new/v_new: (B, K, hd);
-    lengths: (B,) current lengths (the new token's absolute position)."""
-    B = k_new.shape[0]
-    idx = lengths % n_slots if ring else lengths
-    b = jnp.arange(B)
-    k_cache = k_cache.at[b, idx].set(k_new.astype(k_cache.dtype))
-    v_cache = v_cache.at[b, idx].set(v_new.astype(v_cache.dtype))
-    kv_pos = kv_pos.at[b, idx].set(lengths.astype(kv_pos.dtype))
-    return k_cache, v_cache, kv_pos
-
-
 def fill_cache_from_prefill(k, v, positions, n_slots: int):
     """Build (cache, cache_pos) from prefill-computed k/v (B, S, K, hd).
 
@@ -301,9 +290,9 @@ def fill_cache_from_prefill(k, v, positions, n_slots: int):
     padding (right-padded bucketed prefill), so examples in one batch may
     have different true lengths.  Per example, the last ``n_slots`` *valid*
     tokens are kept at their ring slots (slot = pos % n_slots); unfilled
-    slots get pos -1 — decode attention masks them, and the decode-side
-    cache update (`update_cache` semantics, slot = lengths % n_slots)
-    overwrites them in the same layout.
+    slots get pos -1 — decode attention masks them, and a decode step
+    (``blocks._attn_step``: slot = lengths % n_slots in a ring) writes
+    its row into them in the same layout.
     """
     B, S, K, hd = k.shape
     lengths = jnp.sum((positions >= 0).astype(jnp.int32), axis=1)  # (B,)

@@ -7,9 +7,10 @@ Kinds (ModelConfig.layer_pattern entries):
                outputs mean-fused after per-path norm, then MLP
   "rwkv"     — rwkv6 time-mix + channel-mix (handles its own norms)
 
-Every block is a pure function (params, x, cache) -> (x, cache, aux) so the
-layer-stack scan, the per-period cost piece of the roofline analyzer, and
-the smoke tests all share one implementation.
+Every block is a pure function (params, x, cache) -> (x, cache, aux,
+written) so the layer-stack scan, the per-period cost piece of the
+roofline analyzer, and the smoke tests all share one implementation;
+``written`` is the rows a decode step wrote into its KV ring.
 """
 
 from __future__ import annotations
@@ -143,29 +144,48 @@ def _attn_seq(params, x, cfg: ModelConfig, sharder, positions, *,
     return out, entry
 
 
+def write_rows(leaves, slot, rows, layer=None):
+    """``leaves`` with one row a slot written: ``rows[name][b]`` at
+    ``[b, slot[b]]`` of ``leaves[name]``, or at ``[layer, b, slot[b]]``
+    of a layer-stacked leaf.  The decode step writes its token with it
+    into the layer it attends over, and the layer scan the same rows into
+    the stack it carries."""
+    lead = () if layer is None else (layer,)
+    b = jnp.arange(slot.shape[0])
+    out = dict(leaves)
+    for name, row in rows.items():
+        out[name] = out[name].at[lead + (b, slot)].set(row)
+    return out
+
+
 def _attn_step(params, x, cfg: ModelConfig, sharder, lengths, cache, *,
                window: int, positions=None, tile_plan=None):
-    """One-token attention over the cache.  x: (B, 1, d)."""
+    """One-token attention over the cache.  x: (B, 1, d).
+
+    Returns (out, entry, (slot, rows)): ``entry`` is ``cache`` with this
+    token's K, V, position (and int8 scales) written at each slot's
+    ``slot`` (``lengths % n_slots`` in a ring, else ``lengths`` clamped to
+    the last slot), the operand attention reads; ``rows`` holds those
+    values by leaf name.  The decode layer scan (``LM._scan``) carries
+    the ring stacks as loop state and writes these rows into them
+    (:func:`write_rows`), without choosing the slot again; the cache's
+    other leaves it slices per layer and restacks whole."""
     B = x.shape[0]
     pos = positions if positions is not None else lengths[:, None]
     q, k, v = attn.project_qkv(params, x, cfg, sharder, pos)
     n_slots = cache["k"].shape[1]
     ring = window > 0 and n_slots <= window
-    new_kv = _encode_kv(cfg, k, v)
-    idx = lengths % n_slots if ring else jnp.minimum(lengths, n_slots - 1)
-    b = jnp.arange(B)
-    entry = dict(cache)
-    for name in ("k", "v", "k_scale", "v_scale"):
-        if name in entry:
-            entry[name] = entry[name].at[b, idx].set(new_kv[name][:, 0])
-    entry["pos"] = entry["pos"].at[b, idx].set(lengths.astype(jnp.int32))
+    slot = lengths % n_slots if ring else jnp.minimum(lengths, n_slots - 1)
+    rows = {name: a[:, 0] for name, a in _encode_kv(cfg, k, v).items()}
+    rows["pos"] = lengths.astype(jnp.int32)
+    entry = write_rows(cache, slot, rows)
     kc, vc = _decode_kv(cfg, entry)
     out = attn.decode_attention(
         q[:, 0], kc, vc, entry["pos"], lengths, cfg=cfg, sharder=sharder,
         causal=True, window=window, tile_plan=tile_plan)
     out = out.reshape(B, 1, cfg.q_dim)
     out = dot(out.astype(x.dtype), params["wo"])
-    return out, entry
+    return out, entry, (slot, rows)
 
 
 def _cross_attn(params, x, cfg: ModelConfig, sharder, *, enc_out=None,
@@ -217,7 +237,11 @@ def apply_block(params, x, cfg: ModelConfig, kind: str, sharder, *,
                 positions=None, lengths=None, mode: str = "train",
                 cache: Optional[Dict] = None, enc_out=None,
                 causal: bool = True, max_len: int = 0, tile_plan=None):
-    """Returns (x, new_cache_entry, aux_loss).
+    """Returns (x, new_cache_entry, aux_loss, written).
+
+    In decode mode ``written`` is what the attention step wrote into the
+    entry's ring leaves, ``(slot, rows)`` (see :func:`_attn_step`), and
+    None for a block without a KV ring; it is None in the other modes.
 
     In prefill mode ``lengths`` (when not None) marks each example's true
     prompt length within a right-padded batch: recurrent state updates are
@@ -236,10 +260,11 @@ def apply_block(params, x, cfg: ModelConfig, kind: str, sharder, *,
             tile_plan=tile_plan)
         if mode == "train":
             new_cache = None
-        return x, new_cache, jnp.zeros((), F32)
+        return x, new_cache, jnp.zeros((), F32), None
 
     window = cfg.local_window if kind in ("local", "swa_ssm") else 0
     new_cache: Dict = {}
+    written = None
     h = rmsnorm(x, params["norm1"], cfg.norm_eps)
 
     if kind == "swa_ssm":
@@ -249,8 +274,9 @@ def apply_block(params, x, cfg: ModelConfig, kind: str, sharder, *,
         sub_ssm = {k2: cache[k2] for k2 in ("conv_state", "ssd_state")} \
             if cache else None
         if mode == "decode":
-            a_out, a_cache = _attn_step(params["attn"], h, cfg, sharder,
-                                        lengths, sub_attn, window=window)
+            a_out, a_cache, written = _attn_step(
+                params["attn"], h, cfg, sharder, lengths, sub_attn,
+                window=window)
         else:
             a_out, a_cache = _attn_seq(params["attn"], h, cfg, sharder,
                                        positions, window=window, mode=mode,
@@ -267,10 +293,9 @@ def apply_block(params, x, cfg: ModelConfig, kind: str, sharder, *,
             new_cache.update(s_cache)
     else:
         if mode == "decode":
-            a_out, a_cache = _attn_step(params["attn"], h, cfg, sharder,
-                                        lengths, cache, window=window,
-                                        positions=positions,
-                                        tile_plan=tile_plan)
+            a_out, a_cache, written = _attn_step(
+                params["attn"], h, cfg, sharder, lengths, cache,
+                window=window, positions=positions, tile_plan=tile_plan)
         else:
             a_out, a_cache = _attn_seq(params["attn"], h, cfg, sharder,
                                        positions, window=window, mode=mode,
@@ -293,4 +318,4 @@ def apply_block(params, x, cfg: ModelConfig, kind: str, sharder, *,
     h = rmsnorm(x, params["norm2"], cfg.norm_eps)
     f_out, aux = _ffn(params, h, cfg, sharder)
     x = x + f_out
-    return x, (new_cache if mode != "train" else None), aux
+    return x, (new_cache if mode != "train" else None), aux, written

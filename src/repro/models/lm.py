@@ -23,7 +23,8 @@ from repro.configs.base import ModelConfig, ShapeSpec
 from repro.dist.sharding import Sharder
 from repro.models import params as pspec
 from repro.models.attention import cache_slot_count
-from repro.models.blocks import apply_block, attn_cache_entry, block_specs
+from repro.models.blocks import (apply_block, attn_cache_entry,
+                                 block_specs, write_rows)
 from repro.models.layers import COMPUTE_DTYPE, embed, embed_specs, unembed
 from repro.models.params import ParamSpec
 from repro.models.ssm import _d_inner, _n_ssm_heads
@@ -53,6 +54,26 @@ def _store_compute_only(specs, dtype):
         lambda s: dataclasses.replace(s, dtype=dtype)
         if s.compute_only and s.dtype == F32 else s,
         specs, is_leaf=pspec.is_spec)
+
+
+def _split_ring(cache):
+    """(ring, rest) of a period cache: each entry's ring leaves
+    (``LM.PAGEABLE_LEAVES``), for the entries that have any, and its
+    other leaves; ``({}, None)`` without a cache."""
+    if cache is None:
+        return {}, None
+    ring = {key: {n: a for n, a in e.items() if n in LM.PAGEABLE_LEAVES}
+            for key, e in cache.items()}
+    rest = {key: {n: a for n, a in e.items() if n not in LM.PAGEABLE_LEAVES}
+            for key, e in cache.items()}
+    return {key: r for key, r in ring.items() if r}, rest
+
+
+def _join(rest, ring):
+    """A period cache from its entries' other leaves (``rest``) and their
+    ring leaves (``ring``, keyed like ``rest``, entries without any left
+    out)."""
+    return {key: {**e, **ring.get(key, {})} for key, e in rest.items()}
 
 
 def _wider_float(x, dtype) -> bool:
@@ -135,13 +156,16 @@ class LM:
                      causal: bool = True, max_len: int = 0):
         """Apply one scan period (all layers of the pattern).
 
-        Returns (x, new_period_cache_or_None, aux)."""
+        Returns (x, new_period_cache_or_None, aux, written): each cache
+        entry comes back whole, and ``written`` maps each entry whose KV
+        ring a decode step wrote to the rows it wrote, ``(slot, rows)``."""
         cfg = self.cfg
         aux = jnp.zeros((), F32)
         new_cache: Dict[str, Any] = {}
+        written: Dict[str, Any] = {}
         for i, kind in enumerate(cfg.layer_pattern):
             key = f"p{i}"
-            x, c, a = apply_block(
+            x, c, a, w = apply_block(
                 p_params[key], x, cfg, kind, sharder, positions=positions,
                 lengths=lengths, mode=mode, enc_out=enc_out, causal=causal,
                 cache=(p_cache or {}).get(key) if p_cache else None,
@@ -149,21 +173,37 @@ class LM:
             aux = aux + a
             if c is not None:
                 new_cache[key] = c
-        return x, (new_cache or None), aux
+            if w is not None:
+                written[key] = w
+        return x, (new_cache or None), aux, written
 
     def _scan(self, blocks, x, *, positions=None, lengths=None, mode: str,
               sharder: Sharder, cache=None, enc_out=None, causal=True,
               max_len: int = 0, remat: Optional[bool] = None):
+        """Run the period stack as one ``lax.scan``; returns (x, cache or
+        None, aux).
+
+        The cache's ring leaves (``PAGEABLE_LEAVES``: KV, positions, int8
+        scales) ride in the loop state: step ``i`` hands its period layer
+        ``i`` of each stack, and writes back into the stack only the rows
+        the decode step wrote (``blocks.write_rows`` at ``[i, b, slot]``),
+        so a tick moves one row a slot, not the whole layer.  Every other
+        leaf (rwkv and SSM state, cross-attention keys) is sliced out of
+        ``xs`` and restacked from ``ys``; a cache without ring leaves has
+        an empty loop state.  Prefill builds its cache from ``ys``."""
         cfg = self.cfg
         collect = mode in ("prefill", "decode")
         remat = (cfg.remat != "none" and mode == "train") \
             if remat is None else remat
+        ring, rest = _split_ring(cache)
 
         def body(carry, xs):
-            x, aux = carry
-            p_params, p_cache = xs if collect and cache is not None \
-                else (xs, None)
-            x, new_c, a = self.period_apply(
+            x, aux, ring = carry
+            i, p_params, p_rest = xs
+            layer = jax.tree.map(lambda stack: jax.lax.dynamic_index_in_dim(
+                stack, i, keepdims=False), ring)
+            p_cache = None if p_rest is None else _join(p_rest, layer)
+            x, new_c, a, written = self.period_apply(
                 p_params, x, positions=positions, lengths=lengths, mode=mode,
                 sharder=sharder, p_cache=p_cache, enc_out=enc_out,
                 causal=causal, max_len=max_len)
@@ -172,15 +212,21 @@ class LM:
                 # cfg.shard_residual_seq its seq dim shards over the model
                 # axis (re-gathered on recompute) — §Perf lever
                 x = sharder.constrain(x, "batch", "res_seq", None)
-            return (x, aux + a), (new_c if collect else 0)
+            ring = {key: write_rows(r, *written[key], layer=i)
+                    for key, r in ring.items()}
+            ys = {key: {n: leaf for n, leaf in e.items()
+                        if n not in ring.get(key, ())}
+                  for key, e in new_c.items()} if collect else 0
+            return (x, aux + a, ring), ys
 
         if remat:
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                       if cfg.remat == "dots" else None)
             body = jax.checkpoint(body, policy=policy)
-        xs = (blocks, cache) if (collect and cache is not None) else blocks
-        (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), F32)), xs)
-        return x, (caches if collect else None), aux
+        xs = (jnp.arange(cfg.n_periods), blocks, rest)
+        (x, aux, ring), caches = jax.lax.scan(
+            body, (x, jnp.zeros((), F32), ring), xs)
+        return x, (_join(caches, ring) if collect else None), aux
 
     # ------------------------------------------------------------------ stems
     def embed_tokens(self, params, tokens, sharder) -> jax.Array:
@@ -313,7 +359,8 @@ class LM:
                 "lengths": 0}
 
     # KV-ring leaves: paged along their length(-ring) axis by the paged
-    # slot-state manager.  Everything else — rwkv wkv/shift, ssd/conv,
+    # slot-state manager, and carried through the decode layer scan, which
+    # writes one row of each a slot a tick (``_scan``).  Everything else — rwkv wkv/shift, ssd/conv,
     # cross-attn keys, lengths — is per-slot state with no length axis
     # (or, for xk/xv, written whole at prefill), i.e. "one block per
     # slot": the cheap recurrent case.

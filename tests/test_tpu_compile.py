@@ -180,11 +180,24 @@ def _weight_converts(hlo: str, shapes) -> set:
     return out
 
 
+def _layer_scans(jaxpr):
+    """Every ``scan`` equation in ``jaxpr`` and the jaxprs it calls."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _layer_scans(sub)
+
+
 def test_rwkv6_decode_reads_the_serving_copy(one_chip):
     """Compiled for a v5e, a decode step over the serving copy converts no
     weight, where over the f32 masters XLA writes a bf16 copy of every
     weight stack on every call (a reduced rwkv6: the mechanism, not the
-    size)."""
+    size).  Its cache has no KV ring, so the layer scan's loop state holds
+    no cache leaf: the state is sliced from ``xs`` and restacked through
+    ``ys`` as before."""
     from repro.dist.sharding import Sharder
     from repro.models.lm import build_model
     from repro.models.params import is_spec, tree_abstract
@@ -207,6 +220,15 @@ def test_rwkv6_decode_reads_the_serving_copy(one_chip):
     assert _weight_converts(masters, weights) == {
         s.shape for s in jax.tree.leaves(model.param_specs(), is_leaf=is_spec)
         if s.compute_only}
+    stacked = {a.shape for a in jax.tree.leaves(cache["blocks"])}
+    scan, = [e for e in _layer_scans(
+        jax.make_jaxpr(step)(at(copy), cache, tokens).jaxpr)
+        if e.params["length"] == model.cfg.n_periods]
+    first = scan.params["num_consts"]
+    carry = scan.invars[first:first + scan.params["num_carry"]]
+    ys = scan.outvars[scan.params["num_carry"]:]
+    assert not stacked & {v.aval.shape for v in carry}
+    assert stacked <= {v.aval.shape for v in ys}
 
 
 @pytest.fixture(scope="module")
@@ -232,7 +254,14 @@ def test_qwen2_5_14b_chat_tp4_programs(four_chips, monkeypatch):
     (24 slots, a 4096-token cache, 1024-token prefill calls): the decode
     program holds ``flash_decode``, a prefill of the 2048 bucket holds
     ``flash_attention``, and each chip's parameters, cache and
-    temporaries fit its 16 GB."""
+    temporaries fit its 16 GB.
+
+    The layer scan carries the KV rings and writes one row a slot into
+    them, so the decode program holds no ``dynamic-update-slice`` that
+    writes a whole ring stack (each chip's bf16[48,24,1024,8,128] K or
+    V, s32[48,24,1024] positions), as restacking a layer a step through
+    the scan's ``ys`` did; and it needs no more bytes a chip than that
+    program did, 13,206,153,728."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import get_config
@@ -285,6 +314,8 @@ def test_qwen2_5_14b_chat_tp4_programs(four_chips, monkeypatch):
                      pre.as_text())
     cache_bytes = sum(s.size * jnp.dtype(s.dtype).itemsize // 4
                       for s in jax.tree.leaves(cache))
-    assert _per_chip_bytes(dec) < 16e9
+    assert _per_chip_bytes(dec) <= 13_206_153_728
+    assert not re.search(r"= (bf16\[48,24,1024,8,128\]|s32\[48,24,1024\])"
+                         r"\S* dynamic-update-slice\(", dec.as_text())
     # a prefill runs beside the engine's cache
     assert _per_chip_bytes(pre) + cache_bytes < 16e9
