@@ -49,7 +49,7 @@ from repro.configs import (
 )
 from repro.dist.sharding import make_sharder
 from repro.models.lm import build_model
-from repro.plan import WorkloadProfile, io as plan_io
+from repro.plan import ServingPlan, WorkloadProfile, io as plan_io
 from repro.serving import ServingEngine, drive, profile_items
 from repro.serving import metrics as smetrics
 from repro.testing import reduced_config
@@ -450,8 +450,9 @@ def _check_trace_schema() -> None:
 
     def one_run() -> Tracer:
         tracer = Tracer()
-        engine = ServingEngine(model, params, sharder, max_batch=2,
-                               max_len=32, tracer=tracer)
+        plan = ServingPlan(arch="rwkv6-1.6b", max_batch=2, max_len=32)
+        engine = ServingEngine.from_plan(plan, params, model=model,
+                                         sharder=sharder, tracer=tracer)
         drive(engine, profile_items(tiny, vocab_size=cfg.vocab_size,
                                     seed=0))
         return tracer
@@ -496,8 +497,10 @@ def _check_paged_surface() -> None:
     sharder = make_sharder(cfg, None, "decode")
 
     def one_run(layout: str):
-        engine = ServingEngine(model, params, sharder, max_batch=2,
-                               max_len=32, cache_layout=layout)
+        plan = ServingPlan(arch="hymba-1.5b", max_batch=2, max_len=32,
+                           cache_layout=layout)
+        engine = ServingEngine.from_plan(plan, params, model=model,
+                                         sharder=sharder)
         reqs = drive(engine, profile_items(tiny, vocab_size=cfg.vocab_size,
                                            seed=0))
         agg = smetrics.aggregate(reqs, ticks=engine.ticks,

@@ -13,8 +13,8 @@ import numpy as np
 
 from repro.dist.sharding import Sharder
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
-from repro.serving.sampler import SamplerConfig
 from repro.testing import reduced_config
 
 
@@ -28,9 +28,10 @@ def main() -> None:
     cfg = reduced_config(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    engine = ServingEngine(model, params, Sharder(None, {}),
-                           max_batch=4, max_len=48,
-                           sampler=SamplerConfig(temperature=0.8, top_k=20))
+    plan = ServingPlan(arch=args.arch, max_batch=4, max_len=48,
+                       temperature=0.8, top_k=20)
+    engine = ServingEngine.from_plan(plan, params, model=model,
+                                     sharder=Sharder(None, {}))
     rng = np.random.default_rng(0)
     reqs = [engine.submit(rng.integers(0, cfg.vocab_size,
                                        rng.integers(4, 16)).tolist(),

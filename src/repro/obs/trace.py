@@ -44,9 +44,9 @@ REQUEST_PID = 2         # one thread (track) per request uid
 
 CATS = ("request", "engine")
 PHASES = ("X", "i", "C", "M")
-# "quarantine" / "fault" / "retry" events are emitted only when the fault
-# layer actually fires (injected fault or watchdog eviction), so every
-# no-fault trace stays byte-identical to the pre-fault-tolerance engine
+# "quarantine" / "fault" / "retry" events are emitted only by the fault
+# injector and the engine's recovery (repro.serving.recovery), which a
+# plain engine does not have: its traces hold none of them
 REQUEST_SPANS = ("queued", "run", "quarantine")
 REQUEST_INSTANTS = ("submit", "first_token", "preempt", "resume", "shed",
                     "fault", "retry")
@@ -89,12 +89,11 @@ class TraceEvent:
 class Tracer:
     """Collects :class:`TraceEvent`\\ s from the serving engine.
 
-    Attach one via ``ServingEngine.from_plan(..., tracer=Tracer())`` (or
-    the kwargs constructor); the engine calls the ``request_*`` /
-    engine-event hooks below at the host points where it learns each
-    fact, stamped with the *tick* the fact logically happened at.  All
-    hooks are cheap appends — tracing never syncs the device and never
-    perturbs the schedule.
+    Attach one via ``ServingEngine.from_plan(..., tracer=Tracer())``;
+    the engine calls the ``request_*`` / engine-event hooks below at the
+    host points where it learns each fact, stamped with the *tick* the
+    fact logically happened at.  All hooks are cheap appends — tracing
+    never syncs the device and never perturbs the schedule.
     """
 
     def __init__(self) -> None:

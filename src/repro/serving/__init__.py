@@ -1,10 +1,10 @@
 from repro.serving.engine import (  # noqa: F401
-    EngineKilled,
     Request,
     ServingEngine,
 )
 from repro.serving.faults import (  # noqa: F401
     FAULT_KINDS,
+    EngineKilled,
     FaultInjector,
     FaultPlan,
     FaultReport,

@@ -5,7 +5,7 @@ join by prefilling into a free slot and leave on EOS/length without
 disturbing the others — the standard continuous-batching scheme
 (Orca/vLLM) on a fixed-slot KV cache.
 
-The engine is mechanism only; the serving stack is three explicit layers:
+The engine is mechanism only; the serving stack is split in explicit layers:
 
 * :mod:`repro.serving.scheduler` owns *policy* — which queued request to
   admit (FCFS / SPF / EDF) and, for preemptive EDF, which running request
@@ -17,11 +17,13 @@ The engine is mechanism only; the serving stack is three explicit layers:
 * this module owns *execution* — ``step()`` asks the scheduler, moves
   state through the slot manager, runs the prefill / fused-decode
   programs, and reports telemetry;
-* :mod:`repro.plan` owns the *design point* — every constructor knob
-  (capacity, bucket set, chunking, policy, sampling) lives in one frozen
-  :class:`~repro.plan.ServingPlan`; build engines with
-  :meth:`ServingEngine.from_plan` (the kwargs constructor is a shim that
-  assembles a plan internally and behaves identically).
+* :mod:`repro.plan` owns the *design point* — every knob (capacity,
+  bucket set, chunking, policy, sampling) lives in one frozen
+  :class:`~repro.plan.ServingPlan`, and the engine is built from one
+  (:meth:`ServingEngine.from_plan`);
+* :mod:`repro.serving.recovery` owns *fault recovery* — present only
+  when the plan enables the watchdog or a fault injector is attached, so
+  a plain engine's hot path never enters it.
 
 The steady-state hot path is the paper's thesis applied at the host level:
 breaking the serving loop into per-kernel launches (decode, then a host
@@ -74,24 +76,14 @@ from repro.models.lm import LM
 from repro.obs.registry import LiveMetrics, MetricsRegistry
 from repro.obs.spans import span
 from repro.obs.trace import Tracer
-from repro.plan.plan import MIN_BUCKET, ServingPlan
+from repro.plan.plan import ServingPlan
+from repro.serving.recovery import FAULT_COUNTERS, Recovery, idle_journal
 from repro.serving.sampler import SamplerConfig, split_and_sample
-from repro.serving.scheduler import POLICIES, Scheduler, make_scheduler
+from repro.serving.scheduler import Scheduler, make_scheduler
 from repro.serving.slotstate import SlotSnapshot, gather_slots, \
-    make_slot_manager, scatter_slots
+    make_slot_manager
 
 log = logging.getLogger("repro.serving")
-
-
-class EngineKilled(RuntimeError):
-    """Raised by ``step()`` when an attached fault injector schedules a
-    ``kill_engine`` fault at the current tick — the process-crash stand-in
-    for the crash-restart path.  ``faults.drive_resilient`` catches it,
-    restores a fresh engine from the last checkpoint, and replays."""
-
-    def __init__(self, tick: int):
-        super().__init__(f"engine killed by fault injector at tick {tick}")
-        self.tick = tick
 
 
 @dataclasses.dataclass
@@ -144,19 +136,6 @@ def _req_from_json(d: Dict[str, Any]) -> "Request":
     d = dict(d)
     d.pop("saved_next_token", None)
     return Request(**d)
-
-
-def _is_reduced(cfg) -> bool:
-    """Best-effort identity check for the kwargs shim: a config that
-    differs from the registry entry of its own name is a reduced (or
-    otherwise customized) variant.  Unknown names count as reduced —
-    the flag only matters when ``from_plan`` has to rebuild the model."""
-    try:
-        from repro.configs import ARCHS
-
-        return ARCHS.get(cfg.name) != cfg
-    except Exception:  # pragma: no cover - configs import should not fail
-        return True
 
 
 def _kv_cache_bytes(model: LM, sharder: Sharder, max_batch: int,
@@ -241,35 +220,12 @@ def _decode_many(model: LM, sharder: Sharder, sampler: SamplerConfig,
 
 
 class ServingEngine:
-    """Plan-driven construction: every design parameter lives in one
-    :class:`repro.plan.ServingPlan` (``engine.plan``) — build with
-    :meth:`from_plan`.  The historical kwargs constructor is kept as a
-    thin shim that assembles a plan internally, so ``ServingEngine(model,
-    params, sharder, max_batch=..., ...)`` keeps working with a
-    bit-identical schedule to the equivalent ``from_plan`` engine."""
+    """Serves from one :class:`repro.plan.ServingPlan` (``engine.plan``),
+    which holds every design parameter.  Build with :meth:`from_plan`,
+    which fills in the model and the sharder the plan names."""
 
-    def __init__(self, model: LM, params, sharder: Sharder, *,
-                 max_batch: int = 4, max_len: int = 128,
-                 sampler: SamplerConfig = SamplerConfig(), seed: int = 0,
-                 truncate_prompts: bool = False, sync_every: int = 1,
-                 policy: str = "fcfs", preempt: bool = False,
-                 bucketed_prefill: bool = True,
-                 overlap_prefill: bool = True,
-                 shed_late: bool = False,
-                 cache_layout: str = "dense",
-                 plan: Optional[ServingPlan] = None,
-                 tracer: Optional[Tracer] = None):
-        if plan is None:   # kwargs shim: capture the knobs as a plan
-            plan = ServingPlan(
-                arch=model.cfg.name, reduced=_is_reduced(model.cfg),
-                max_batch=max_batch, max_len=max_len,
-                cache_layout=cache_layout,
-                sync_every=sync_every, policy=policy, preempt=preempt,
-                bucketed_prefill=bucketed_prefill,
-                overlap_prefill=overlap_prefill, shed_late=shed_late,
-                temperature=sampler.temperature, top_k=sampler.top_k,
-                truncate_prompts=truncate_prompts,
-                provenance={"source": "engine-kwargs"})
+    def __init__(self, plan: ServingPlan, model: LM, sharder: Sharder,
+                 params, *, seed: int = 0, tracer: Optional[Tracer] = None):
         plan.validate()
         if plan.tile_plans and hasattr(model, "with_tile_plans"):
             # thread the DSE-chosen kernel geometry into every block call
@@ -285,19 +241,13 @@ class ServingEngine:
         self.max_len = plan.max_len
         self.sampler = SamplerConfig(temperature=plan.temperature,
                                      top_k=plan.top_k)
-        self.truncate_prompts = plan.truncate_prompts
         self.sync_every = int(plan.sync_every)
-        self.policy = plan.policy
-        self.bucketed_prefill = plan.bucketed_prefill
-        self.overlap_prefill = plan.overlap_prefill
-        self.shed_late = plan.shed_late
         self._buckets = plan.resolved_buckets()
         # one registry for the whole stack: scheduler + slot-state counters
         # register into it, so reset_telemetry() covers them by construction
         self.metrics = MetricsRegistry()
         self.scheduler: Scheduler = make_scheduler(
             plan.policy, preempt=plan.preempt, registry=self.metrics)
-        self.cache_layout = plan.cache_layout
         self._paged = plan.cache_layout != "dense"
         self.sm = make_slot_manager(model, self.max_batch, self.max_len,
                                     layout=plan.cache_layout,
@@ -328,21 +278,6 @@ class ServingEngine:
                               "over, summed over slots and ticks")
         self._c_prefill_tokens = c("engine.prefill_tokens",
                                    "prompt tokens prefilled")
-        # fault-tolerance counters: registered always (so reset_telemetry
-        # covers them), but surfaced via fault_stats() rather than stats()
-        # — no-fault runs keep their historical stats()/BENCH bytes
-        self._c_f_injected = c("faults.injected",
-                               "faults fired by the attached injector")
-        self._c_f_quarantined = c("faults.quarantined",
-                                  "slots quarantined (poison / dropped "
-                                  "readback / watchdog)")
-        self._c_f_retries = c("faults.retries",
-                              "request rollbacks (re-queued from the last "
-                              "good snapshot or re-prefilled)")
-        self._c_f_shed = c("faults.shed",
-                           "requests shed after exhausting retry_budget")
-        self._c_f_watchdog = c("faults.watchdog_evictions",
-                               "stuck slots evicted by the watchdog")
         self.metrics.gauge("engine.ticks", "virtual-clock tick counter",
                            fn=lambda: float(self._tick))
         self.metrics.gauge("engine.params_cast_bytes",
@@ -373,22 +308,10 @@ class ServingEngine:
         self._uid_next = 0   # plain int (not itertools.count): journaled
         #                      by checkpoint() so restored engines mint
         #                      identical uids for replayed submissions
-        # ---- fault tolerance (inert unless an injector is attached or
-        # ---- the plan enables the watchdog — see _fault_mode) ----------
-        self.retry_budget = int(plan.retry_budget)
-        self.watchdog_ticks = int(plan.watchdog_ticks)
-        self._injector = None                   # faults.FaultInjector
-        self.fault_events: List[Dict[str, Any]] = []
-        self._awaiting: Dict[int, Dict[str, Any]] = {}  # uid -> open event
-        self._recovery: Dict[int, Tuple[Optional[SlotSnapshot], int]] = {}
-        self._stalled: Set[int] = set()         # slots frozen by stall_slot
-        self._poison_outstanding: Set[int] = set()  # scribbled, not yet seen
-        self._last_progress = np.zeros((self.max_batch,), np.int64)
-        self._drop_readback = False             # armed: next chunk readback
-        #                                         is discarded wholesale
-        self._fail_prefill = False              # armed: next prefill call
-        #                                         fails before launch
-        self._prefill_blocked = False           # a prefill failed this tick
+        # fault recovery: none unless the plan enables the watchdog or an
+        # injector is attached (attach_injector)
+        self.recovery: Optional[Recovery] = (
+            Recovery(self) if plan.watchdog_ticks > 0 else None)
         self.restored_from: Optional[Dict[str, Any]] = None
         self._key = jax.random.PRNGKey(seed)
         sampler, max_len, k = self.sampler, self.max_len, self.sync_every
@@ -431,8 +354,7 @@ class ServingEngine:
             from repro.dist.sharding import make_sharder
 
             sharder = make_sharder(model.cfg, None, plan.shard_mode)
-        return cls(model, params, sharder, seed=seed, plan=plan,
-                   tracer=tracer)
+        return cls(plan, model, sharder, params, seed=seed, tracer=tracer)
 
     def lower_decode(self):
         """Lower the fused decode-chunk program that :meth:`step` launches,
@@ -442,61 +364,6 @@ class ServingEngine:
             self.params, self.sm.cache, self.sm.next_token, self._key,
             self.sm.active, self.sm.eos, self.sm.remaining,
             np.int32(self.sync_every), np.bool_(False))
-
-    # ------------------------------------------------- back-compat accessors
-    @property
-    def cache(self):
-        return self.sm.cache
-
-    @property
-    def slots(self) -> List[Optional[Request]]:
-        return self.sm.slots
-
-    @property
-    def queue(self):
-        return self.scheduler.queue
-
-    # counters live in the registry; these read-only views keep the
-    # historical attribute names (engine.completed, engine.shed, ...)
-    @property
-    def completed(self) -> int:
-        return self._c_completed.value
-
-    @property
-    def total_tokens(self) -> int:
-        return self._c_total_tokens.value
-
-    @property
-    def instant_admits(self) -> int:
-        return self._c_instant_admits.value
-
-    @property
-    def host_syncs(self) -> int:
-        return self._c_host_syncs.value
-
-    @property
-    def decode_chunks(self) -> int:
-        return self._c_decode_chunks.value
-
-    @property
-    def prefill_calls(self) -> int:
-        return self._c_prefill_calls.value
-
-    @property
-    def preemptions(self) -> int:
-        return self._c_preemptions.value
-
-    @property
-    def resumes(self) -> int:
-        return self._c_resumes.value
-
-    @property
-    def evicted_tokens(self) -> int:
-        return self._c_evicted_tokens.value
-
-    @property
-    def shed(self) -> int:
-        return self._c_shed.value
 
     def enable_live_metrics(self, window: int = 64) -> LiveMetrics:
         """Attach a rolling :class:`repro.obs.LiveMetrics` window (last
@@ -520,7 +387,7 @@ class ServingEngine:
         limit = self.max_len - 1  # >= 1 cache slot left for generation
         truncated = False
         if len(prompt) > limit:
-            if not self.truncate_prompts:
+            if not self.plan.truncate_prompts:
                 raise ValueError(
                     f"prompt length {len(prompt)} exceeds max_len-1 = "
                     f"{limit}; raise max_len or construct the engine with "
@@ -548,7 +415,7 @@ class ServingEngine:
             # obs.observe.fit_profile sees the *offered* load, not just
             # what admission control let through
             self.tracer.request_submit(req, self._tick)
-        if (self.shed_late and deadline is not None
+        if (self.plan.shed_late and deadline is not None
                 and self._provably_late(req)):
             # deadline-aware admission control: reject work that cannot
             # meet its SLO even if admitted this very tick, instead of
@@ -602,7 +469,7 @@ class ServingEngine:
         """Padded prefill length for an n-token prompt: the smallest
         bucket that fits it.  The bucket set comes from the plan
         (``plan.buckets``, defaulting to the historical pow2 set)."""
-        if not self.bucketed_prefill:
+        if not self.plan.bucketed_prefill:
             return n
         for b in self._buckets:
             if b >= n:
@@ -627,8 +494,8 @@ class ServingEngine:
     def _step(self, max_ticks: Optional[int]) -> bool:
         budget = self.sync_every if max_ticks is None \
             else max(1, min(int(max_ticks), self.sync_every))
-        if self._injector is not None:
-            self._apply_due_faults()   # may raise EngineKilled
+        if self.recovery is not None:
+            self.recovery.on_step()
         with span("engine.schedule"):
             n_instant = self._schedule()
         if self.tracer is not None:
@@ -680,26 +547,16 @@ class ServingEngine:
         """Host side of a chunk's readback: append its tokens, retire
         finished requests, report its ticks, refresh the slot mirrors."""
         self._c_host_syncs.inc()
-        # fault path: a dropped readback discards the whole chunk's tokens
-        # (and the overlapped first tokens riding on it) — every slot that
-        # decoded rolls back to its last recovery point
-        dropped = self._drop_readback and n > 0
-        self._drop_readback = False
-        if not dropped:
-            for p, fv in zip(self._pending, firsts):
-                for req, row in zip(p.reqs, p.rows):
+        # slots whose tokens the recovery rejects (their requests roll
+        # back to their last recovery point in on_chunk below)
+        bad = (self.recovery.on_readback(n, active_idx)
+               if self.recovery is not None else ())
+        for p, fv in zip(self._pending, firsts):
+            for req, row, slot in zip(p.reqs, p.rows, p.slots):
+                if slot not in bad:
                     req.output.append(int(fv[row]))
                     self._c_total_tokens.inc()
         self._pending = []
-        if dropped:
-            bad = [i for i in active_idx if self.sm.slots[i] is not None
-                   and self.sm.active[i]]
-        elif self._injector is not None and n > 0:
-            bad = self._scan_poisoned(active_idx)
-        else:
-            bad = []
-        bad_set = set(bad)
-        progressed: Set[int] = set()
         base = self._tick
         if self.tracer is not None:
             self.tracer.decode_chunk(base, n, len(active_idx))
@@ -707,14 +564,13 @@ class ServingEngine:
             n_active = kv = 0
             for i in active_idx:
                 req = self.sm.slots[i]
-                if req is None or not acts[j, i] or i in bad_set:
+                if req is None or not acts[j, i] or i in bad:
                     continue
                 n_active += 1
                 # the tick fed the last served token at position
                 # len(prompt) + len(output) - 1 and attended over it and
                 # every position before it, up to the ring's length
                 kv += min(len(req.prompt) + len(req.output), self.max_len)
-                progressed.add(i)
                 req.output.append(int(toks[j, i]))
                 self._c_total_tokens.inc()
                 if dones[j, i]:
@@ -731,20 +587,20 @@ class ServingEngine:
             # refresh the host mirrors from the authoritative slot table
             self.sm.refresh_after_chunk(toks[n - 1])
         else:
-            # fault mode only: every occupied slot is stalled, so the
-            # fused loop ran zero ticks.  Time still advances one tick so
-            # the watchdog can reach its threshold and evict.
+            # the recovery froze every occupied slot, so the fused loop
+            # ran zero ticks.  Time still advances one tick so its
+            # watchdog can reach its threshold and evict.
             self._observe_tick(self._tick, n_instant / self.max_batch)
             self._tick += 1
-        if self._fault_mode:
-            self._fault_epilogue(bad, dropped, progressed)
+        if self.recovery is not None:
+            self.recovery.on_chunk(n, acts, active_idx)
 
     # ------------------------------------------------------------- internals
     def _finish(self, req: Request, tick: int) -> None:
         req.done = True
         req.t_done = tick
-        if self._fault_mode:
-            self._recovery.pop(req.uid, None)
+        if self.recovery is not None:
+            self.recovery.on_finish(req)
         self._c_completed.inc()
         self.finished.append(req)
         if self.tracer is not None:
@@ -777,238 +633,22 @@ class ServingEngine:
                 self.tracer.counter(tick, "padding_waste",
                                     self.sm.padding_waste())
 
-    # -------------------------------------------------------- fault tolerance
-    @property
-    def _fault_mode(self) -> bool:
-        """True when any recovery machinery must run: an injector is
-        attached or the plan's watchdog is enabled.  Everything in this
-        section is gated on it, so plain engines keep a byte-identical
-        schedule, telemetry, and trace."""
-        return self._injector is not None or self.watchdog_ticks > 0
-
+    # -------------------------------------------------------- fault recovery
     def attach_injector(self, injector) -> None:
-        """Attach a :class:`repro.serving.faults.FaultInjector`; its due
-        faults are applied at the top of every :meth:`step`."""
-        if injector.plan.needs_watchdog() and self.watchdog_ticks <= 0:
-            raise ValueError(
-                "fault plan contains stall_slot faults but the engine's "
-                "watchdog is off; set plan.watchdog_ticks > 0 so stalled "
-                "requests can be evicted and retried")
-        self._injector = injector
+        """Attach a :class:`repro.serving.faults.FaultInjector`, building
+        the engine's :class:`~repro.serving.recovery.Recovery` if it has
+        none; its due faults fire at the start of every :meth:`step`."""
+        recovery = self.recovery or Recovery(self)
+        recovery.attach(injector)   # raises before the engine takes it
+        self.recovery = recovery
 
     def fault_stats(self) -> Dict[str, float]:
         """Fault/recovery counter view — separate from :meth:`stats` so
-        no-fault runs keep their historical stats() keys byte-for-byte."""
-        return self.metrics.view({
-            "injected": "faults.injected",
-            "quarantined": "faults.quarantined",
-            "retries": "faults.retries",
-            "shed": "faults.shed",
-            "watchdog_evictions": "faults.watchdog_evictions",
-        })
-
-    def _apply_due_faults(self) -> None:
-        """Fire every fault the injector scheduled at or before the current
-        tick.  Slot faults (poison/stall) stay armed while no slot is
-        occupied — they need a victim — and fall back to the lowest
-        occupied slot when their nominal target is empty, so a fault plan
-        written against one workload stays meaningful on another."""
-        for idx, spec in self._injector.due(self._tick):
-            if spec.kind == "kill_engine":
-                self._injector.fire(idx, self._tick)
-                self._c_f_injected.inc()
-                self.fault_events.append(
-                    {"kind": "kill_engine", "tick": self._tick,
-                     "uid": None, "slot": None, "recovered_at": None})
-                if self.tracer is not None:
-                    self.tracer.engine_fault(self._tick, "kill_engine")
-                raise EngineKilled(self._tick)
-            if spec.kind == "drop_readback":
-                self._injector.fire(idx, self._tick)
-                self._c_f_injected.inc()
-                self._drop_readback = True
-                if self.tracer is not None:
-                    self.tracer.engine_fault(self._tick, "drop_readback")
-            elif spec.kind == "fail_prefill":
-                self._injector.fire(idx, self._tick)
-                self._c_f_injected.inc()
-                self._fail_prefill = True
-            else:   # poison_slot / stall_slot need an occupied victim
-                occ = self.sm.occupied()
-                if not occ:
-                    continue   # not fired: stays due for a later tick
-                slot = (spec.slot if spec.slot in occ else occ[0])
-                self._injector.fire(idx, self._tick)
-                self._c_f_injected.inc()
-                if self.tracer is not None:
-                    self.tracer.engine_fault(self._tick, spec.kind,
-                                             slot=slot)
-                if spec.kind == "poison_slot":
-                    self._poison(slot, spec)
-                else:
-                    self._stalled.add(slot)
-                    self.sm.active[slot] = False
-
-    def _poison(self, slot: int, spec) -> None:
-        """Corrupt ``slot``'s cache column in place: overwrite every float
-        leaf with NaN (``mode="nan"``) or seeded large-magnitude garbage
-        salted with ±Inf (``mode="garbage"``) — both detectable by the
-        non-finite guard scan after the next chunk."""
-        col = jax.device_get(gather_slots(self.sm.cache, self.sm.axes,
-                                          [slot]))
-        rng = np.random.default_rng(spec.seed)
-
-        def scribble(a):
-            a = np.asarray(a)
-            if not jnp.issubdtype(a.dtype, jnp.floating):
-                return a
-            if spec.mode == "nan":
-                return np.full_like(a, np.nan)
-            g = (rng.standard_normal(a.shape) * 1e30).astype(np.float32)
-            g[rng.uniform(size=a.shape) < 0.25] = np.inf
-            g.reshape(-1)[0] = -np.inf   # at least one non-finite value
-            return g.astype(a.dtype)
-
-        bad = jax.tree.map(scribble, col)
-        self.sm.cache = scatter_slots(self.sm.cache, self.sm.axes, [slot],
-                                      bad)
-        self._poison_outstanding.add(slot)
-
-    def _scan_poisoned(self, active_idx: List[int]) -> List[int]:
-        """Per-slot non-finite guard over every float cache leaf, reduced
-        on device to one (max_batch,) flag vector — runs only while a
-        poison is outstanding, so fault-free chunks pay nothing."""
-        self._poison_outstanding = {
-            s for s in self._poison_outstanding
-            if self.sm.slots[s] is not None}
-        if not self._poison_outstanding:
-            return []
-        flags = np.zeros((self.max_batch,), bool)
-        cache = self.sm.cache
-        checks = []
-        for leaf, ax in zip(jax.tree.leaves(cache),
-                            jax.tree.leaves(self.sm.axes)):
-            if not jnp.issubdtype(leaf.dtype, jnp.floating):
-                continue
-            red = tuple(d for d in range(leaf.ndim) if d != ax)
-            checks.append(jnp.any(~jnp.isfinite(leaf), axis=red))
-        for bad in jax.device_get(checks):
-            flags |= np.asarray(bad)
-        caught = [i for i in active_idx
-                  if flags[i] and self.sm.slots[i] is not None]
-        self._poison_outstanding -= set(caught)
-        return caught
-
-    def _quarantine(self, slot: int, tick: int, kind: str) -> None:
-        """Pull a bad slot out of service: scrub the column (no residue
-        for the next tenant), release the slot, roll the request back."""
-        req = self.sm.slots[slot]
-        self._c_f_quarantined.inc()
-        if kind == "watchdog":
-            self._c_f_watchdog.inc()
-        self.sm.scrub([slot])
-        self.sm.release(slot)
-        self._stalled.discard(slot)
-        self._poison_outstanding.discard(slot)
-        self._rollback(req, tick, kind, slot)
-
-    def _rollback(self, req: Request, tick: int, kind: str,
-                  slot: Optional[int] = None) -> None:
-        """Re-queue ``req`` from its last good recovery point (or from
-        scratch when none exists), charging one retry; past the budget the
-        request is shed — the engine never emits tokens it cannot vouch
-        for.  Emits the fault event + trace instants."""
-        event = {"kind": kind, "tick": tick, "uid": req.uid, "slot": slot,
-                 "recovered_at": None}
-        self.fault_events.append(event)
-        self._awaiting[req.uid] = event
-        if self.tracer is not None:
-            self.tracer.request_fault(req, tick, kind, slot)
-        req.retries += 1
-        rp = self._recovery.get(req.uid)
-        if req.retries > self.retry_budget:
-            req.shed = True
-            event["shed"] = True
-            event["recovered_at"] = tick
-            self._awaiting.pop(req.uid, None)
-            self._recovery.pop(req.uid, None)
-            self._c_f_shed.inc()
-            if self.tracer is not None:
-                self.tracer.request_quarantine(req, tick, tick)
-                self.tracer.request_shed(req, tick)
-            if self.live is not None:
-                self.live.observe_request(req, tick)
-            log.debug("shed req %d at tick %d: retry budget %d exhausted "
-                      "(%s)", req.uid, tick, self.retry_budget, kind)
-            return
-        self._c_f_retries.inc()   # counts re-queues, not the shedding try
-        if rp is not None:
-            snap, n_out = rp
-            del req.output[n_out:]
-            req.saved = snap
-        else:
-            del req.output[:]
-            req.saved = None
-        self.scheduler.requeue_front(req)
-        if self.tracer is not None:
-            self.tracer.request_retry(req, tick, req.retries)
-        log.debug("rolled back req %d at tick %d (%s, retry %d/%d, "
-                  "%d tokens kept)", req.uid, tick, kind, req.retries,
-                  self.retry_budget, len(req.output))
-
-    def _mark_recovered(self, req: Request) -> None:
-        """A rolled-back request made it back into a slot: close its open
-        fault event and emit the quarantine span (fault tick -> now)."""
-        event = self._awaiting.pop(req.uid, None)
-        if event is None:
-            return
-        event["recovered_at"] = self._tick
-        if self.tracer is not None:
-            self.tracer.request_quarantine(req, event["tick"], self._tick)
-
-    def _fault_epilogue(self, bad: List[int], dropped: bool,
-                        progressed: Set[int]) -> None:
-        """End-of-chunk fault bookkeeping: quarantine flagged slots, run
-        the watchdog, re-assert stalls over the refreshed mirrors, and
-        refresh every survivor's recovery point."""
-        for i in progressed:
-            self._last_progress[i] = self._tick
-        for i in bad:
-            if self.sm.slots[i] is not None:
-                self._quarantine(i, self._tick,
-                                 "drop_readback" if dropped else "poison")
-        # refresh_after_chunk derived `active` from occupancy: re-freeze
-        # slots the injector stalled (their request is wedged, not done)
-        for i in list(self._stalled):
-            if self.sm.slots[i] is None:
-                self._stalled.discard(i)
-            else:
-                self.sm.active[i] = False
-        if self.watchdog_ticks > 0:
-            for i in self.sm.occupied():
-                if self._tick - self._last_progress[i] >= self.watchdog_ticks:
-                    self._quarantine(i, self._tick, "watchdog")
-        self._refresh_recovery()
-
-    def _refresh_recovery(self) -> None:
-        """Snapshot every occupied slot as its request's last *good*
-        recovery point (the guard scan / quarantine above already removed
-        every slot known bad, so what remains is vouched-for state).
-
-        Stalled slots are skipped: the fused chunk advances *every*
-        lane's device state (only the token/remaining writebacks are
-        masked by ``active``), so a wedged slot's column silently drifts
-        from its frozen outputs — its recovery point must stay the last
-        pre-stall snapshot or the watchdog rollback resumes from state
-        the request never emitted tokens for."""
-        occ = [i for i in self.sm.occupied() if i not in self._stalled]
-        if not occ:
-            return
-        snaps = self.sm.snapshot_many(occ)
-        self._c_host_syncs.inc()
-        for slot, snap in zip(occ, snaps):
-            req = self.sm.slots[slot]
-            self._recovery[req.uid] = (snap, len(req.output))
+        no-fault runs keep their historical stats() keys byte-for-byte;
+        all zero for an engine with no recovery."""
+        if self.recovery is None:
+            return dict.fromkeys(FAULT_COUNTERS, 0)
+        return self.recovery.stats()
 
     def _merge_pending_tokens(self):
         """Decode-chunk input tokens: the host mirror, with overlapped
@@ -1113,16 +753,15 @@ class ServingEngine:
                 req.saved = None
                 req.t_resumes.append(self._tick)
                 self._c_resumes.inc()
-                if self._fault_mode:
-                    self._last_progress[slot] = self._tick
-                    self._mark_recovered(req)
+                if self.recovery is not None:
+                    self.recovery.on_admit(req, slot)
                 if self.tracer is not None:
                     self.tracer.request_resume(req, self._tick, slot)
                 log.debug("resumed req %d into slot %d at tick %d",
                           req.uid, slot, self._tick)
             if not fresh:
                 continue
-            if self.bucketed_prefill:
+            if self.plan.bucketed_prefill:
                 groups: Dict[int, List[Request]] = {}
                 for req in fresh:
                     groups.setdefault(self.bucket(len(req.prompt)),
@@ -1136,22 +775,27 @@ class ServingEngine:
             # budget) frees the slot for further same-tick admissions, and
             # that decision needs the sampled token on host: such rounds
             # take the synchronous path
-            overlap = (self.overlap_prefill
+            overlap = (self.plan.overlap_prefill
                        and not any(r.eos_id is not None
                                    or r.max_new_tokens == 1 for r in fresh))
+            blocked = False
             for S, reqs in grouped:
                 # a bucket's calls hold at most plan.prefill_rows(S) rows
-                step = (self.plan.prefill_rows(S) if self.bucketed_prefill
-                        else len(reqs))
+                step = (self.plan.prefill_rows(S)
+                        if self.plan.bucketed_prefill else len(reqs))
                 for i in range(0, len(reqs), step):
+                    group = reqs[i:i + step]
+                    if (self.recovery is not None
+                            and self.recovery.prefill_fails(group)):
+                        blocked = True
+                        continue
                     with span("engine.prefill"):
-                        n_instant += self._prefill_group(
-                            S, reqs[i:i + step], free, overlap)
-            if self._prefill_blocked:
-                # a fault just failed the prefill call and requeued its
-                # group; stop admitting this tick or we'd pick the same
-                # requests again in an endless same-tick loop
-                self._prefill_blocked = False
+                        n_instant += self._prefill_group(S, group, free,
+                                                         overlap)
+            if blocked:
+                # a failed prefill requeued its group; stop admitting this
+                # tick or we'd pick the same requests again in an endless
+                # same-tick loop
                 break
         return n_instant
 
@@ -1164,22 +808,7 @@ class ServingEngine:
         ``overlap=True`` keeps the sampled first tokens on device and
         defers the host bookkeeping to the decode chunk's readback, so
         the prefill never blocks the chunk launch."""
-        if self._fail_prefill:
-            # injected fault: the prefill call fails before launch.  The
-            # whole group rolls back (fresh requests: re-prefill from
-            # scratch, charged one retry) and admission stops this tick.
-            self._fail_prefill = False
-            self._prefill_blocked = True
-            self.fault_events.append(
-                {"kind": "fail_prefill", "tick": self._tick, "uid": None,
-                 "slot": None, "recovered_at": None})
-            if self.tracer is not None:
-                self.tracer.engine_fault(self._tick, "fail_prefill",
-                                         rows=len(reqs))
-            for req in reqs:
-                self._rollback(req, self._tick, "fail_prefill")
-            return 0
-        rows = self.plan.prefill_rows(S) if self.bucketed_prefill \
+        rows = self.plan.prefill_rows(S) if self.plan.bucketed_prefill \
             else len(reqs)
         tokens = np.zeros((rows, S), np.int32)
         lengths = np.ones((rows,), np.int32)   # dummy rows: 1 valid token
@@ -1208,9 +837,8 @@ class ServingEngine:
                 slot = free.pop(0)
                 self.sm.grant(slot, req, None)
                 req.t_admit = req.t_first = self._tick
-                if self._fault_mode:
-                    self._last_progress[slot] = self._tick
-                    self._mark_recovered(req)
+                if self.recovery is not None:
+                    self.recovery.on_admit(req, slot)
                 grant_rows.append(r_i)
                 grant_slots.append(slot)
             self.sm.insert_from_prefill(grant_slots, grant_rows, cacheN)
@@ -1228,19 +856,19 @@ class ServingEngine:
             req.output.append(tok)
             self._c_total_tokens.inc()
             req.t_admit = req.t_first = self._tick
-            if self._fault_mode:
-                self._mark_recovered(req)
             if ((req.eos_id is not None and tok == req.eos_id)
                     or len(req.output) >= req.max_new_tokens):
                 # done at the prefill token: never occupies a slot
+                if self.recovery is not None:
+                    self.recovery.on_admit(req, None)
                 self._finish(req, self._tick)
                 n_instant += 1
                 self._c_instant_admits.inc()
                 continue
             slot = free.pop(0)
             self.sm.grant(slot, req, tok)
-            if self._fault_mode:
-                self._last_progress[slot] = self._tick
+            if self.recovery is not None:
+                self.recovery.on_admit(req, slot)
             grant_rows.append(r_i)
             grant_slots.append(slot)
         if grant_rows:
@@ -1262,10 +890,11 @@ class ServingEngine:
         """Journal the complete engine state through a
         :class:`repro.checkpoint.CheckpointManager` step (named by the
         current tick): PRNG key + slot mirrors + every occupied slot's
-        cache column + every evicted snapshot column as the array tree,
-        and requests / queue order / tick / uid counter / fault state as
-        JSON extra.  :meth:`restore` rebuilds an engine that replays the
-        remaining schedule bit-identically.
+        cache column + every evicted snapshot column (+ the recovery's
+        points) as the array tree, and requests / queue order / tick /
+        uid counter / the recovery's fault fields as JSON extra.
+        :meth:`restore` rebuilds an engine that replays the remaining
+        schedule bit-identically.
 
         Must run between steps (no overlapped admissions in flight) — the
         driver checkpoints at chunk boundaries, where that always holds."""
@@ -1289,6 +918,8 @@ class ServingEngine:
             queue_json.append(_req_to_json(req))
             if req.saved is not None:
                 saved_cols[f"u{req.uid}"] = req.saved.cache_col
+        faults = (self.recovery.journal() if self.recovery is not None
+                  else idle_journal(self.max_batch))
         state = {
             "key": self._key,
             "next_token": np.asarray(self.sm.next_token),
@@ -1306,18 +937,21 @@ class ServingEngine:
             "slots": slots_json,
             "queue": queue_json,
             "finished": [_req_to_json(r) for r in self.finished],
-            "stalled": sorted(self._stalled),
-            "last_progress": [int(x) for x in self._last_progress],
+            "stalled": faults["stalled"],
+            "last_progress": faults["last_progress"],
             "util_history": list(self.util_history),
             "kv_history": list(self.kv_history),
             "prefill_history": [list(n) for n in self.prefill_history],
             "counters": {
-                "total_tokens": self.total_tokens,
-                "instant_admits": self.instant_admits,
-                "shed": self.shed,
-                "faults": {k: int(v) for k, v in self.fault_stats().items()},
+                "total_tokens": self._c_total_tokens.value,
+                "instant_admits": self._c_instant_admits.value,
+                "shed": self._c_shed.value,
+                "faults": faults["faults"],
             },
         }}
+        if self.recovery is not None:
+            state["point_cols"], extra["engine"]["points"] = \
+                self.recovery.journal_points()
         manager.save(self._tick, state, extra=extra, blocking=blocking)
         return self._tick
 
@@ -1327,11 +961,11 @@ class ServingEngine:
                 step: Optional[int] = None,
                 tracer: Optional[Tracer] = None) -> "ServingEngine":
         """Rebuild an engine from a :meth:`checkpoint` step (latest when
-        ``step`` is None).  The restored engine's remaining schedule —
-        tick stamps, outputs, uids minted for replayed submissions — is
-        bit-identical to the uninterrupted engine's from the checkpoint
-        tick, because every input to the deterministic loop (PRNG key,
-        cache columns, mirrors, queue order, counters) is journaled."""
+        ``step`` is None).  The restored engine's remaining schedule — tick stamps, outputs, uids
+        minted for replayed submissions — is bit-identical to the
+        uninterrupted engine's from the checkpoint tick, because every
+        input to the deterministic loop (PRNG key, cache columns, mirrors,
+        queue order, counters, recovery points) is journaled."""
         if step is None:
             step = manager.latest_step()
             if step is None:
@@ -1348,6 +982,9 @@ class ServingEngine:
         plan = plan_io.from_dict(ex["plan"])
         eng = cls.from_plan(plan, params, model=model, sharder=sharder,
                             tracer=tracer)
+        if "points" in ex and eng.recovery is None:
+            # the journal was written by an engine with a recovery
+            eng.recovery = Recovery(eng)
         occ = sorted(int(k) for k in ex["slots"])
         saved_uids = [d["uid"] for d in ex["queue"]
                       if "saved_next_token" in d]
@@ -1362,6 +999,10 @@ class ServingEngine:
             "saved_cols": {f"u{u}": gather_slots(eng.sm.cache, eng.sm.axes,
                                                  [0]) for u in saved_uids},
         }
+        if "points" in ex:
+            template["point_cols"] = {
+                f"u{u}": gather_slots(eng.sm.cache, eng.sm.axes, [0])
+                for u in ex["points"]}
         st = manager.restore(template, step=step)
         # slot-resident requests first: the public restore path scatters
         # each journaled column back (covers dense and paged layouts)
@@ -1370,7 +1011,6 @@ class ServingEngine:
             snap = SlotSnapshot(st["slot_cols"][f"s{i}"],
                                 int(st["next_token"][i]))
             eng.sm.restore(i, snap, req)
-            eng._recovery[req.uid] = (snap, len(req.output))
         # then overwrite the mirrors wholesale: restore() above recomputed
         # remaining/active heuristically; the journaled arrays are exact
         # (stalled slots inactive, mid-flight remaining counts, ...)
@@ -1393,13 +1033,8 @@ class ServingEngine:
         eng._c_total_tokens.inc(int(c.get("total_tokens", 0)))
         eng._c_instant_admits.inc(int(c.get("instant_admits", 0)))
         eng._c_shed.inc(int(c.get("shed", 0)))
-        fc = c.get("faults", {})
-        for ctr, key in ((eng._c_f_injected, "injected"),
-                         (eng._c_f_quarantined, "quarantined"),
-                         (eng._c_f_retries, "retries"),
-                         (eng._c_f_shed, "shed"),
-                         (eng._c_f_watchdog, "watchdog_evictions")):
-            ctr.inc(int(fc.get(key, 0)))
+        if eng.recovery is not None:
+            eng.recovery.load(ex, st.get("point_cols", {}))
         eng._tick = int(ex["tick"])
         eng._uid_next = int(ex["uid_next"])
         eng.util_history = list(ex.get("util_history", []))
@@ -1408,9 +1043,6 @@ class ServingEngine:
         eng.prefill_history = [
             tuple(int(x) for x in n) for n in ex.get(
                 "prefill_history", [[]] * len(eng.util_history))]
-        eng._stalled = set(int(s) for s in ex.get("stalled", []))
-        eng._last_progress[:] = np.asarray(ex["last_progress"],
-                                           dtype=np.int64)
         eng.restored_from = {"step": step, "clock_now": ex["clock_now"]}
         return eng
 
@@ -1484,6 +1116,4 @@ class ServingEngine:
         return out
 
 
-# re-exported for back-compat: the policy registry lives in scheduler.py
-__all__ = ["Request", "ServingEngine", "EngineKilled", "POLICIES",
-           "MIN_BUCKET"]
+__all__ = ["Request", "ServingEngine"]

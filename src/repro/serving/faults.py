@@ -32,11 +32,13 @@ Three pieces:
   uninterrupted run — the crash costs wall time, never correctness
   (proven in ``tests/test_faults.py``).
 
-The *recovery* half — the numeric guard that quarantines poisoned
-slots, the bounded-retry/shed policy, the stuck-slot watchdog, and
-``checkpoint()``/``restore()`` themselves — lives in
-:class:`repro.serving.engine.ServingEngine`; this module only decides
-when to hurt it.
+The injector also decides *how* to hurt the engine
+(:meth:`FaultInjector.apply_due`): it scribbles a cache column, freezes
+a slot, arms a lost readback or a failed prefill, or raises
+:class:`EngineKilled`.  The *recovery* half — the numeric guard that
+quarantines poisoned slots, the bounded-retry/shed policy and the
+stuck-slot watchdog — is :mod:`repro.serving.recovery`;
+``checkpoint()`` and ``restore()`` stay on the engine.
 """
 
 from __future__ import annotations
@@ -47,13 +49,18 @@ import math
 import time
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
-from repro.serving.engine import EngineKilled, Request, ServingEngine
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.serving.engine import Request, ServingEngine
+from repro.serving.slotstate import gather_slots, scatter_slots
 from repro.serving.workload import VirtualClock, WorkloadItem
 
 FAULT_SCHEMA = "fault_plan/v1"
 
-# every fault class the injector can schedule; the engine's recovery
-# layer (engine._apply_due_faults and friends) must handle each one
+# every fault class the injector can schedule (FaultInjector.apply_due
+# applies each one; repro.serving.recovery recovers from it)
 FAULT_KINDS = (
     "poison_slot",     # scribble NaN/garbage into a slot's cache column
     "drop_readback",   # lose one decode chunk's device->host readback
@@ -62,6 +69,17 @@ FAULT_KINDS = (
     "kill_engine",     # raise EngineKilled out of step() — crash-restart
 )
 POISON_MODES = ("nan", "garbage")
+
+
+class EngineKilled(RuntimeError):
+    """Raised out of ``ServingEngine.step()`` when the attached injector
+    fires a ``kill_engine`` fault — the process-crash stand-in for the
+    crash-restart path.  :func:`drive_resilient` catches it, restores a
+    fresh engine from the last checkpoint, and replays."""
+
+    def __init__(self, tick: int):
+        super().__init__(f"engine killed by fault injector at tick {tick}")
+        self.tick = tick
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,12 +200,12 @@ class FaultPlan:
 class FaultInjector:
     """One-shot consumption ledger over a :class:`FaultPlan`.
 
-    The engine polls :meth:`due` at each host intervention and calls
-    :meth:`fire` for every spec it actually applied; a fired spec never
-    fires again.  The ledger lives *outside* the engine on purpose:
-    :func:`drive_resilient` re-attaches the same injector to a restored
-    engine, so a consumed kill fault stays consumed across the restart
-    it caused."""
+    The engine's recovery calls :meth:`apply_due` at each host
+    intervention; it fires (:meth:`fire`) every spec it actually applies,
+    and a fired spec never fires again.  The ledger lives *outside* the
+    engine on purpose: :func:`drive_resilient` re-attaches the same
+    injector to a restored engine, so a consumed kill fault stays
+    consumed across the restart it caused."""
 
     def __init__(self, plan: FaultPlan):
         self.plan = plan.validate()
@@ -209,6 +227,69 @@ class FaultInjector:
 
     def pending(self) -> int:
         return len(self.plan.faults) - len(self._fired)
+
+    def apply_due(self, engine: ServingEngine) -> None:
+        """Fire every fault scheduled at or before the engine's tick,
+        recording each in ``engine.recovery``.  Slot faults (poison/stall)
+        stay armed while no slot is occupied — they need a victim — and
+        fall back to the lowest occupied slot when their nominal target
+        is empty, so a fault plan written against one workload stays
+        meaningful on another."""
+        rec, tracer, tick = engine.recovery, engine.tracer, engine.ticks
+        for idx, spec in self.due(tick):
+            slot = None
+            if spec.kind in ("poison_slot", "stall_slot"):
+                occ = engine.sm.occupied()
+                if not occ:
+                    continue   # not fired: stays due for a later tick
+                slot = spec.slot if spec.slot in occ else occ[0]
+            self.fire(idx, tick)
+            rec.counters["injected"].inc()
+            if spec.kind == "kill_engine":
+                rec.fault_events.append(
+                    {"kind": "kill_engine", "tick": tick, "uid": None,
+                     "slot": None, "recovered_at": None})
+                if tracer is not None:
+                    tracer.engine_fault(tick, "kill_engine")
+                raise EngineKilled(tick)
+            if spec.kind == "fail_prefill":
+                rec.fail_prefill = True   # traced when the prefill fails
+                continue
+            if tracer is not None:
+                args = {} if slot is None else {"slot": slot}
+                tracer.engine_fault(tick, spec.kind, **args)
+            if spec.kind == "drop_readback":
+                rec.drop_readback = True
+            elif spec.kind == "poison_slot":
+                self._poison(engine, slot, spec)
+                rec.poisoned.add(slot)
+            else:
+                rec.stalled.add(slot)
+                engine.sm.active[slot] = False
+
+    @staticmethod
+    def _poison(engine: ServingEngine, slot: int, spec: FaultSpec) -> None:
+        """Corrupt ``slot``'s cache column in place: overwrite every float
+        leaf with NaN (``mode="nan"``) or seeded large-magnitude garbage
+        salted with ±Inf (``mode="garbage"``) — both detectable by the
+        recovery's non-finite guard scan after the next chunk."""
+        sm = engine.sm
+        col = jax.device_get(gather_slots(sm.cache, sm.axes, [slot]))
+        rng = np.random.default_rng(spec.seed)
+
+        def scribble(a):
+            a = np.asarray(a)
+            if not jnp.issubdtype(a.dtype, jnp.floating):
+                return a
+            if spec.mode == "nan":
+                return np.full_like(a, np.nan)
+            g = (rng.standard_normal(a.shape) * 1e30).astype(np.float32)
+            g[rng.uniform(size=a.shape) < 0.25] = np.inf
+            g.reshape(-1)[0] = -np.inf   # at least one non-finite value
+            return g.astype(a.dtype)
+
+        sm.cache = scatter_slots(sm.cache, sm.axes, [slot],
+                                 jax.tree.map(scribble, col))
 
 
 @dataclasses.dataclass
@@ -308,11 +389,12 @@ def drive_resilient(engine: ServingEngine, items: Sequence[WorkloadItem],
                         injector.fire(idx, engine.ticks)
                         injector.log[-1]["expired"] = True
             clock.busy_seconds = busy
+            rec = engine.recovery
             return FaultReport(
                 requests=[by_uid[u] for u in sorted(by_uid)],
                 engine=engine, n_restarts=n_restarts,
                 restart_ticks_lost=ticks_lost,
-                fault_events=list(engine.fault_events))
+                fault_events=list(rec.fault_events) if rec else [])
         budget = sync_every
         if i < len(pending):
             gap = pending[i].t - clock.now
@@ -329,12 +411,12 @@ def drive_resilient(engine: ServingEngine, items: Sequence[WorkloadItem],
             engine = ServingEngine.restore(
                 manager, dead.params, model=dead.model,
                 sharder=dead.sharder, tracer=dead.tracer)
-            engine.fault_events.extend(dead.fault_events)
+            engine.recovery.fault_events.extend(dead.recovery.fault_events)
             # the kill fired after the last checkpoint, so the restored
             # counters do not include it — yet the restart it caused is
             # part of the surviving timeline (unlike other post-checkpoint
             # faults, which roll back and never re-fire)
-            engine._c_f_injected.inc()
+            engine.recovery.counters["injected"].inc()
             if injector is not None:
                 engine.attach_injector(injector)
             ticks_lost += max(0, kill.tick - engine.ticks)
@@ -368,8 +450,6 @@ def make_storm(*, duration: int, seed: int = 0,
     mid-run so there is state worth losing).  Pure function of the
     arguments — the chaos benchmark's cells are as replayable as the
     serving ones."""
-    import numpy as np
-
     rng = np.random.default_rng(seed)
     kinds = tuple(kinds)
     for k in kinds:

@@ -12,6 +12,7 @@ import pytest
 
 from repro.dist.sharding import Sharder
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine, VirtualClock, drive, make_workload
 from repro.serving.sampler import SamplerConfig, sample, split_and_sample
 from repro.testing import reduced_config
@@ -27,11 +28,13 @@ def setup():
     return cfg, model, params
 
 
-def _engine(setup, **kw):
+def _engine(setup, seed=0, **knobs):
     cfg, model, params = setup
-    kw.setdefault("max_batch", 2)
-    kw.setdefault("max_len", 32)
-    return ServingEngine(model, params, NOSH, **kw)
+    knobs.setdefault("max_batch", 2)
+    knobs.setdefault("max_len", 32)
+    return ServingEngine.from_plan(ServingPlan(arch=cfg.name, **knobs),
+                                   params, model=model, sharder=NOSH,
+                                   seed=seed)
 
 
 # --------------------------------------------------- fused sample-in-step
@@ -47,7 +50,8 @@ def test_fused_sample_matches_host_sampler(setup, sampler):
     sequence replayed manually with model calls + split_and_sample."""
     cfg, model, params = setup
     prompt = [5, 9, 3, 7, 2]
-    eng = _engine(setup, max_batch=1, seed=11, sampler=sampler)
+    eng = _engine(setup, max_batch=1, seed=11,
+                  temperature=sampler.temperature, top_k=sampler.top_k)
     r = eng.submit(list(prompt), max_new_tokens=5)
     eng.run()
     assert r.done and len(r.output) == 5
@@ -84,7 +88,8 @@ def test_sample_helper_matches_sample(setup):
 
 
 def _run_closed_loop(setup, sync_every, prompts, max_new, sampler):
-    eng = _engine(setup, seed=123, sync_every=sync_every, sampler=sampler)
+    eng = _engine(setup, seed=123, sync_every=sync_every,
+                  temperature=sampler.temperature, top_k=sampler.top_k)
     reqs = [eng.submit(list(p), max_new_tokens=m) for p, m in
             zip(prompts, max_new)]
     eng.run()
@@ -233,7 +238,7 @@ def test_overlap_prefill_schedule_identical_fewer_syncs(setup):
 
     def serve(overlap):
         eng = _engine(setup, max_batch=4, seed=3, overlap_prefill=overlap,
-                      sampler=SamplerConfig(temperature=0.9, top_k=6))
+                      temperature=0.9, top_k=6)
         items = make_workload("poisson", rate=0.9, duration=24.0, seed=5,
                               vocab_size=cfg.vocab_size, prompt_len=(2, 14),
                               max_new_tokens=(2, 8))

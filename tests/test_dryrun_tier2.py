@@ -61,6 +61,7 @@ def test_paged_dense_equivalence_grid(arch, block):
 
     from repro.dist.sharding import Sharder
     from repro.models.lm import build_model
+    from repro.plan import ServingPlan
     from repro.serving import ServingEngine, VirtualClock, drive, \
         make_workload
     from repro.testing import reduced_config
@@ -74,8 +75,10 @@ def test_paged_dense_equivalence_grid(arch, block):
                           max_new_tokens=(2, 8))
 
     def serve(layout):
-        eng = ServingEngine(model, params, sharder, max_batch=3,
-                            max_len=32, seed=13, cache_layout=layout)
+        plan = ServingPlan(arch=arch, max_batch=3, max_len=32,
+                           cache_layout=layout)
+        eng = ServingEngine.from_plan(plan, params, model=model,
+                                      sharder=sharder, seed=13)
         reqs = drive(eng, [i for i in items], VirtualClock())
         return eng, [(r.uid, r.output, r.t_admit, r.t_first, r.t_done)
                      for r in reqs]

@@ -7,8 +7,8 @@ import pytest
 
 from repro.dist.sharding import Sharder
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine, VirtualClock, drive, make_workload
-from repro.serving.sampler import SamplerConfig
 from repro.testing import reduced_config
 
 
@@ -26,12 +26,14 @@ def setup(request):
     return cfg, model, params, Sharder(None, {}), request.param
 
 
-def _engine(setup, **kw):
-    cfg, model, params = setup[:3]
-    kw.setdefault("max_batch", 2)
-    kw.setdefault("max_len", 32)
-    kw.setdefault("cache_layout", setup[4])
-    return ServingEngine(model, params, setup[3], **kw)
+def _engine(setup, seed=0, **knobs):
+    cfg, model, params, sharder, layout = setup
+    knobs.setdefault("max_batch", 2)
+    knobs.setdefault("max_len", 32)
+    knobs.setdefault("cache_layout", layout)
+    return ServingEngine.from_plan(ServingPlan(arch=cfg.name, **knobs),
+                                   params, model=model, sharder=sharder,
+                                   seed=seed)
 
 
 def test_drained_run_conserves_requests(setup):
@@ -43,7 +45,7 @@ def test_drained_run_conserves_requests(setup):
             for i in range(7)]
     eng.run()
     assert all(r.done for r in reqs)
-    assert eng.completed == len(reqs) == len(eng.finished)
+    assert eng.stats()["completed"] == len(reqs) == len(eng.finished)
     assert sorted(r.uid for r in eng.finished) == [r.uid for r in reqs]
     assert not eng.has_work()
     assert eng.stats()["active"] == 0 and eng.stats()["queued"] == 0
@@ -60,7 +62,7 @@ def test_output_bound_under_open_loop_arrivals(setup):
                           vocab_size=cfg.vocab_size, prompt_len=(2, 6),
                           max_new_tokens=(1, 6))
     reqs = drive(eng, items, VirtualClock())
-    assert len(reqs) == eng.completed
+    assert len(reqs) == eng.stats()["completed"]
     for r in reqs:
         assert r.done and 1 <= len(r.output) <= r.max_new_tokens
 
@@ -94,7 +96,8 @@ def test_reset_telemetry_requires_drained_engine(setup):
         eng.reset_telemetry()
     eng.run()
     eng.reset_telemetry()
-    assert eng.ticks == 0 and eng.completed == 0 and not eng.finished
+    assert eng.ticks == 0 and eng.stats()["completed"] == 0
+    assert not eng.finished
     assert r.done  # the drained request itself is untouched
 
 
@@ -129,8 +132,7 @@ def test_fixed_seed_bit_reproducible_across_constructions(setup):
     cfg = setup[0]
 
     def one():
-        eng = _engine(setup, seed=123,
-                      sampler=SamplerConfig(temperature=0.8, top_k=5))
+        eng = _engine(setup, seed=123, temperature=0.8, top_k=5)
         items = make_workload("mmpp", rate=0.4, duration=12.0, seed=9,
                               vocab_size=cfg.vocab_size, prompt_len=(2, 5),
                               max_new_tokens=(2, 5))

@@ -12,6 +12,7 @@ of the same workload token-for-token (rollback restores the exact
 pre-fault state; greedy decode then reproduces the same tokens).
 """
 
+import inspect
 import json
 
 import jax
@@ -25,6 +26,7 @@ from repro.serving import (FaultInjector, FaultPlan, FaultReport, FaultSpec,
                            ServingEngine, VirtualClock, drive,
                            drive_resilient, make_workload)
 from repro.serving.faults import make_storm
+from repro.serving.recovery import Recovery
 from repro.testing import reduced_config
 
 
@@ -218,7 +220,7 @@ def test_fault_free_stats_surface_unchanged(setup):
     eng = _engine(setup)
     drive(eng, _items(setup), VirtualClock())
     assert not any(k.startswith("fault") for k in eng.stats())
-    assert eng.fault_events == []
+    assert eng.recovery is None
     assert eng.fault_stats() == {"injected": 0, "quarantined": 0,
                                  "retries": 0, "shed": 0,
                                  "watchdog_evictions": 0}
@@ -229,23 +231,60 @@ def test_fault_free_stats_surface_unchanged(setup):
 # ---------------------------------------------------------------------------
 
 
-def test_crash_restart_bit_identical(setup, tmp_path):
+@pytest.mark.parametrize("stalled", (False, True),
+                         ids=("plain", "stalled-under-watchdog"))
+def test_crash_restart_bit_identical(setup, tmp_path, stalled):
     """THE tentpole proof: kill the engine mid-run; the restored run loses
     zero requests and finishes with a schedule bit-identical to a run
-    that was never killed."""
+    that was never killed.  ``stalled``: the restored checkpoint was
+    taken while a slot was wedged under the watchdog, so the recovery's
+    journal fields (stalled slots, last progress, fault counters) must
+    round-trip for the schedules to match."""
     items = _items(setup)
-    base = _baseline(setup, items)
-    mgr = CheckpointManager(str(tmp_path))
-    inj = FaultInjector(FaultPlan((FaultSpec("kill_engine", tick=9),)))
-    rep = drive_resilient(_engine(setup), items, VirtualClock(),
+    if stalled:
+        knobs = {"watchdog_ticks": 6}
+        faults = (FaultSpec("stall_slot", tick=5, slot=1),)
+        base = _schedule(drive_resilient(
+            _engine(setup, **knobs), items, VirtualClock(),
+            injector=FaultInjector(FaultPlan(faults))).requests)
+    else:
+        knobs, faults = {}, ()
+        base = _baseline(setup, items)
+    mgr = CheckpointManager(str(tmp_path), keep=1000)
+    inj = FaultInjector(FaultPlan(faults + (FaultSpec("kill_engine",
+                                                      tick=9),)))
+    rep = drive_resilient(_engine(setup, **knobs), items, VirtualClock(),
                           injector=inj, manager=mgr, checkpoint_every=4)
     assert rep.n_restarts == 1
+    journal = mgr.manifest(rep.engine.restored_from["step"])["extra"]
+    assert bool(journal["engine"]["stalled"]) == stalled
     assert not rep.lost_uids() and not rep.shed_uids
     assert sorted(_schedule(rep.requests)) == sorted(base)   # no dup uids
     assert _schedule(rep.requests) == base
-    assert rep.engine.fault_stats()["injected"] == 1
+    assert rep.engine.fault_stats()["injected"] == 1 + len(faults)
     kill_evs = [e for e in rep.fault_events if e["kind"] == "kill_engine"]
     assert len(kill_evs) == 1   # the consumed kill did not re-fire
+
+
+def test_plain_engine_never_enters_recovery(setup, monkeypatch):
+    """A plan with no watchdog and no injector builds no recovery, and
+    the hot path never calls into it: with every Recovery method patched
+    to raise, the engine serves a workload to the end with the same
+    schedule."""
+    items = _items(setup)
+    base = _baseline(setup, items)
+
+    def entered(*args, **kwargs):
+        raise AssertionError("a plain engine entered its recovery")
+
+    for name, fn in inspect.getmembers(Recovery, inspect.isfunction):
+        monkeypatch.setattr(Recovery, name, entered)
+    eng = _engine(setup)
+    assert eng.recovery is None
+    reqs = drive(eng, items, VirtualClock())
+    assert all(r.done for r in reqs)
+    assert _schedule(reqs) == base
+    assert eng.fault_stats() == dict.fromkeys(eng.fault_stats(), 0)
 
 
 def test_kill_without_manager_rejected(setup):

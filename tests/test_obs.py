@@ -21,7 +21,7 @@ from repro.obs.trace import TICK_US, TRACE_SCHEMA
 from repro.serving import ServingEngine, VirtualClock, drive
 from repro.serving.engine import Request
 from repro.serving.workload import profile_items
-from repro.plan.plan import WorkloadProfile
+from repro.plan.plan import ServingPlan, WorkloadProfile
 from repro.testing import reduced_config
 
 
@@ -247,15 +247,23 @@ def setup():
     return cfg, model, params, Sharder(None, {})
 
 
+def _engine(setup, tracer=None, **knobs):
+    cfg, model, params, sharder = setup
+    knobs.setdefault("max_batch", 2)
+    knobs.setdefault("max_len", 32)
+    return ServingEngine.from_plan(ServingPlan(arch=cfg.name, **knobs),
+                                   params, model=model, sharder=sharder,
+                                   tracer=tracer)
+
+
 _PROFILE = WorkloadProfile(kind="poisson", rate=0.6, duration=24.0,
                            deadline_slack=3.0)
 
 
 def _traced_run(setup, **kw):
-    cfg, model, params, sharder = setup
+    cfg = setup[0]
     tracer = Tracer()
-    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32,
-                        tracer=tracer, **kw)
+    eng = _engine(setup, tracer=tracer, **kw)
     live = eng.enable_live_metrics(window=100_000)
     items = profile_items(_PROFILE, vocab_size=cfg.vocab_size, seed=0)
     reqs = drive(eng, items, VirtualClock())
@@ -273,7 +281,7 @@ def test_same_seed_traces_are_byte_identical_and_valid(setup):
             "queue_depth"} <= names
     # every completed request emitted its full lifecycle
     dones = [e for e in tr1.events if e.name == "run"]
-    assert len(dones) == eng.completed
+    assert len(dones) == eng.stats()["completed"]
     # span durations line up with the request stamps
     for ev in dones:
         req = next(r for r in eng.finished
@@ -361,9 +369,7 @@ def test_fragmentation_gauges_registered_and_consistent(setup, layout):
     engine's shared MetricsRegistry under both layouts and satisfy
     resident = useful + waste; dense resident is the constant worst-case
     commitment, paged resident tracks occupancy."""
-    cfg, model, params, sharder = setup
-    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32,
-                        cache_layout=layout)
+    eng = _engine(setup, cache_layout=layout)
     snap = eng.metrics.snapshot()
     for name in ("slots.blocks_free", "slots.bytes_resident",
                  "slots.padding_waste"):
@@ -387,9 +393,8 @@ def test_aggregate_metrics_block_untouched_by_gauges(setup):
     from repro.serving import metrics as smetrics
 
     def one(layout):
-        cfg, model, params, sharder = setup
-        eng = ServingEngine(model, params, sharder, max_batch=2,
-                            max_len=32, cache_layout=layout)
+        cfg = setup[0]
+        eng = _engine(setup, cache_layout=layout)
         items = profile_items(_PROFILE, vocab_size=cfg.vocab_size, seed=0)
         reqs = drive(eng, items, VirtualClock())
         return smetrics.aggregate(reqs, ticks=eng.ticks,
@@ -452,8 +457,7 @@ def test_span_names_are_unique_and_plain():
 def test_engine_step_emits_its_phases_in_order(setup, tmp_path):
     from repro.obs import SPANS
 
-    cfg, model, params, sharder = setup
-    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32)
+    eng = _engine(setup)
     eng.submit([1, 2, 3], max_new_tokens=3)
     eng.run()                            # compiles prefill and decode
     eng.submit([4, 5, 6, 7], max_new_tokens=3)
@@ -491,8 +495,7 @@ def test_rnn_serve_emits_plan_operands_launch(cell, tmp_path):
 
 
 def test_jitted_programs_have_stable_names(setup):
-    cfg, model, params, sharder = setup
-    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32)
+    eng = _engine(setup)
     assert "decode_program" in eng.lower_decode().as_text()
     assert eng._decode_many.__name__ == "decode_program"
     assert eng._prefill.__name__ == "prefill_program"

@@ -25,6 +25,7 @@ from hypothesis import strategies as st
 
 from repro.dist.sharding import Sharder
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
 from repro.serving.paged import BlockPool, PagedSlotManager, \
     canonicalize_cache
@@ -35,6 +36,12 @@ from repro.testing import reduced_config
 ARCHS = ("rwkv6-1.6b", "qwen2.5-14b", "hymba-1.5b")
 MAX_LEN = 32
 BLOCK = 8
+
+
+def _engine(model, params, sharder, *, seed, **knobs):
+    plan = ServingPlan(arch=model.cfg.name, max_len=MAX_LEN, **knobs)
+    return ServingEngine.from_plan(plan, params, model=model,
+                                   sharder=sharder, seed=seed)
 
 
 @pytest.fixture(scope="module")
@@ -95,8 +102,8 @@ def _lockstep(built, arch: str, seed: int, *, n_ops: int = 24,
     rng = np.random.default_rng(seed)
 
     def make(layout):
-        return ServingEngine(model, params, sharder, max_batch=max_batch,
-                             max_len=MAX_LEN, seed=11, cache_layout=layout)
+        return _engine(model, params, sharder, seed=11,
+                       max_batch=max_batch, cache_layout=layout)
 
     dense, paged = make("dense"), make(f"paged:{BLOCK}")
     reqs_d, reqs_p = [], []
@@ -211,8 +218,8 @@ def test_restore_into_different_slot_cross_layout(built, arch):
     cfg, model, params, sharder = built(arch)
 
     def run_and_snap(layout):
-        eng = ServingEngine(model, params, sharder, max_batch=3,
-                            max_len=MAX_LEN, seed=3, cache_layout=layout)
+        eng = _engine(model, params, sharder, seed=3, max_batch=3,
+                      cache_layout=layout)
         req = eng.submit([7, 3, 9, 2, 8], max_new_tokens=8)
         for _ in range(3):
             eng.step()
@@ -238,8 +245,8 @@ def test_snapshot_restore_roundtrip_bit_exact(built, layout):
     """snapshot -> release -> restore into another slot leaves the live
     column bit-identical to the original (same manager, either layout)."""
     cfg, model, params, sharder = built("hymba-1.5b")
-    eng = ServingEngine(model, params, sharder, max_batch=3,
-                        max_len=MAX_LEN, seed=5, cache_layout=layout)
+    eng = _engine(model, params, sharder, seed=5, max_batch=3,
+                  cache_layout=layout)
     req = eng.submit([4, 8, 15, 16, 23, 42], max_new_tokens=8)
     for _ in range(4):
         eng.step()
@@ -322,8 +329,8 @@ def test_paged_bytes_resident_never_exceeds_dense(built, arch):
 
     def engines():
         for lay in LAYOUTS:
-            yield ServingEngine(model, params, sharder, max_batch=3,
-                                max_len=MAX_LEN, seed=2, cache_layout=lay)
+            yield _engine(model, params, sharder, seed=2, max_batch=3,
+                          cache_layout=lay)
 
     dense, paged = engines()
     assert paged.sm.bytes_resident() <= dense.sm.bytes_resident()
@@ -351,7 +358,6 @@ def test_paged_bytes_resident_never_exceeds_dense(built, arch):
 
 def _fault_lockstep(built, arch: str, seed: int, *, n_ops: int = 20,
                     max_batch: int = 3) -> None:
-    from repro.plan.plan import ServingPlan
     from repro.serving import FaultInjector, FaultPlan, FaultSpec
 
     cfg, model, params, sharder = built(arch)
@@ -366,11 +372,8 @@ def _fault_lockstep(built, arch: str, seed: int, *, n_ops: int = 20,
         for j in range(3)))
 
     def make(layout):
-        plan = ServingPlan(
-            arch=arch, reduced=True, max_batch=max_batch, max_len=MAX_LEN,
-            cache_layout=layout, retry_budget=2, watchdog_ticks=3,
-            provenance={"source": "fault-lockstep"})
-        eng = ServingEngine(model, params, sharder, seed=11, plan=plan)
+        eng = _engine(model, params, sharder, seed=11, max_batch=max_batch,
+                      cache_layout=layout, retry_budget=2, watchdog_ticks=3)
         eng.attach_injector(FaultInjector(fplan))   # per-engine ledger
         return eng
 
@@ -397,8 +400,7 @@ def _fault_lockstep(built, arch: str, seed: int, *, n_ops: int = 20,
     assert out_d == out_p, f"{arch} seed={seed}: fault outcomes diverged"
     assert dense.fault_stats() == paged.fault_stats(), \
         f"{arch} seed={seed}: fault stats diverged"
-    assert [e for e in dense.fault_events] == \
-        [e for e in paged.fault_events], \
+    assert dense.recovery.fault_events == paged.recovery.fault_events, \
         f"{arch} seed={seed}: fault events diverged"
 
 

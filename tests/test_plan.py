@@ -1,6 +1,6 @@
-"""repro.plan: ServingPlan round-trips, kwargs-shim equivalence, the
-autotuner's determinism, deadline-aware shedding, batched eviction, and
-the batch-aware kernel tile search."""
+"""repro.plan: ServingPlan round-trips, the autotuner's determinism,
+deadline-aware shedding, batched eviction, and the batch-aware kernel
+tile search."""
 
 import json
 
@@ -38,12 +38,10 @@ def built():
     return cfg, model, params, sharder
 
 
-def _schedule(engine, n=6, max_new=5):
-    reqs = [engine.submit([1 + i, 2, 3 + i], max_new_tokens=max_new)
-            for i in range(n)]
-    engine.run()
-    return [(r.t_submit, r.t_admit, r.t_first, r.t_done, tuple(r.output))
-            for r in reqs]
+def _engine(built, seed=0, **knobs):
+    cfg, model, params, sharder = built
+    return ServingEngine.from_plan(ServingPlan(arch=ARCH, **knobs), params,
+                                   model=model, sharder=sharder, seed=seed)
 
 
 # ---------------------------------------------------------------------------
@@ -111,23 +109,8 @@ def test_workload_profile_round_trip():
 
 
 # ---------------------------------------------------------------------------
-# Engine: kwargs shim == from_plan
+# Engine: the plan drives it
 # ---------------------------------------------------------------------------
-
-
-def test_kwargs_shim_matches_from_plan_bit_exact(built):
-    cfg, model, params, sharder = built
-    kwargs = dict(max_batch=2, max_len=32, sync_every=2, policy="spf")
-    e1 = ServingEngine(model, params, sharder, seed=7, **kwargs)
-    plan = ServingPlan(arch=ARCH, max_len=32, max_batch=2, sync_every=2,
-                       policy="spf")
-    e2 = ServingEngine.from_plan(plan, params, model=model, sharder=sharder,
-                                 seed=7)
-    assert _schedule(e1) == _schedule(e2)
-    # the shim records an equivalent plan (provenance aside)
-    import dataclasses
-    assert dataclasses.replace(e1.plan, provenance={}, reduced=True) == \
-        dataclasses.replace(e2.plan, provenance={}, reduced=True)
 
 
 def test_explicit_bucket_set_drives_prefill_shapes(built):
@@ -270,9 +253,8 @@ def test_expected_tokens_per_slot_p95():
 
 
 def test_shed_late_rejects_provably_late_only(built):
-    cfg, model, params, sharder = built
-    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32,
-                        shed_late=True, policy="edf")
+    eng = _engine(built, max_batch=2, max_len=32, shed_late=True,
+                  policy="edf")
     # needs 8 ticks minimum; deadline 3 is provably late at tick 0
     late = eng.submit([1, 2, 3], max_new_tokens=8, deadline=3.0)
     assert late.shed and not late.done
@@ -294,8 +276,7 @@ def test_shed_late_rejects_provably_late_only(built):
 
 
 def test_shed_disabled_by_default(built):
-    cfg, model, params, sharder = built
-    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32)
+    eng = _engine(built, max_batch=2, max_len=32)
     r = eng.submit([1, 2, 3], max_new_tokens=8, deadline=1.0)
     assert not r.shed            # admission control is opt-in
     eng.run()
@@ -303,9 +284,7 @@ def test_shed_disabled_by_default(built):
 
 
 def test_shed_eos_requests_use_conservative_bound(built):
-    cfg, model, params, sharder = built
-    eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32,
-                        shed_late=True)
+    eng = _engine(built, max_batch=2, max_len=32, shed_late=True)
     # an eos_id request could retire at its prefill token, so only a
     # deadline earlier than one tick from now is provably late
     ok = eng.submit([1, 2, 3], max_new_tokens=8, eos_id=0, deadline=1.0)
@@ -320,8 +299,7 @@ def test_shed_eos_requests_use_conservative_bound(built):
 
 
 def test_snapshot_many_is_one_transfer_and_bit_exact(built, monkeypatch):
-    cfg, model, params, sharder = built
-    eng = ServingEngine(model, params, sharder, max_batch=3, max_len=32)
+    eng = _engine(built, max_batch=3, max_len=32)
     for i in range(3):
         eng.submit([5 + i, 6, 7 + i], max_new_tokens=10)
     eng.step()
@@ -348,11 +326,8 @@ def test_snapshot_many_is_one_transfer_and_bit_exact(built, monkeypatch):
 
 
 def test_preempt_many_matches_sequential_schedule(built):
-    cfg, model, params, sharder = built
-
     def run(batched):
-        eng = ServingEngine(model, params, sharder, max_batch=3, max_len=32,
-                            seed=0)
+        eng = _engine(built, max_batch=3, max_len=32)
         reqs = [eng.submit([5 + i, 6, 7], max_new_tokens=12)
                 for i in range(3)]
         eng.step()

@@ -12,8 +12,8 @@ import pytest
 
 from repro.dist.sharding import Sharder
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
-from repro.serving.sampler import SamplerConfig
 from repro.testing import reduced_config
 
 NOSH = Sharder(None, {})
@@ -27,15 +27,17 @@ def setup():
     return cfg, model, params
 
 
-def _engine(setup, **kw):
+def _engine(setup, seed=0, **knobs):
     cfg, model, params = setup
-    kw.setdefault("max_batch", 2)
-    kw.setdefault("max_len", 32)
-    return ServingEngine(model, params, NOSH, **kw)
+    knobs.setdefault("max_batch", 2)
+    knobs.setdefault("max_len", 32)
+    return ServingEngine.from_plan(ServingPlan(arch=cfg.name, **knobs),
+                                   params, model=model, sharder=NOSH,
+                                   seed=seed)
 
 
 def _solo_output(model, params, prompt, max_new, max_len=32):
-    eng = ServingEngine(model, params, NOSH, max_batch=1, max_len=max_len)
+    eng = _engine((model.cfg, model, params), max_batch=1, max_len=max_len)
     r = eng.submit(list(prompt), max_new_tokens=max_new)
     eng.run()
     return r.output
@@ -57,7 +59,7 @@ def test_preempt_evict_resume_bit_exact(arch):
     prompt = [5, 9, 3, 7, 2]
     base = _solo_output(model, params, prompt, 10)
 
-    eng = ServingEngine(model, params, NOSH, max_batch=1, max_len=32)
+    eng = _engine((cfg, model, params), max_batch=1)
     a = eng.submit(list(prompt), max_new_tokens=10)
     for _ in range(3):
         eng.step()
@@ -86,10 +88,8 @@ def test_immediate_resume_is_schedule_noop(setup):
     uninterrupted run exactly (stochastic sampling included — same slot,
     same tick sequence, same key stream)."""
     prompts = [[1, 2, 3], [4, 5, 6, 7, 8]]
-    sampler = SamplerConfig(temperature=0.8, top_k=5)
-
     def serve(preempt_at):
-        eng = _engine(setup, seed=7, sampler=sampler)
+        eng = _engine(setup, seed=7, temperature=0.8, top_k=5)
         reqs = [eng.submit(list(p), max_new_tokens=8) for p in prompts]
         for k in range(3):
             eng.step()
@@ -149,8 +149,7 @@ def test_edf_preempts_running_for_tighter_deadline(setup):
     base_slow = _solo_output(model, params, slow_prompt, 10)
     base_fast = _solo_output(model, params, fast_prompt, 4)
 
-    eng = ServingEngine(model, params, NOSH, max_batch=1, max_len=32,
-                        policy="edf", preempt=True)
+    eng = _engine(setup, max_batch=1, policy="edf", preempt=True)
     slow = eng.submit(list(slow_prompt), max_new_tokens=10, deadline=500.0)
     for _ in range(3):
         eng.step()
@@ -168,8 +167,7 @@ def test_edf_preempts_running_for_tighter_deadline(setup):
 
 def test_edf_without_preempt_never_evicts(setup):
     cfg, model, params = setup
-    eng = ServingEngine(model, params, NOSH, max_batch=1, max_len=32,
-                        policy="edf", preempt=False)
+    eng = _engine(setup, max_batch=1, policy="edf", preempt=False)
     slow = eng.submit([5, 9, 3], max_new_tokens=8, deadline=500.0)
     eng.step()
     urgent = eng.submit([8, 6], max_new_tokens=2, deadline=5.0)

@@ -5,8 +5,8 @@ import numpy as np
 import pytest
 
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
-from repro.serving.sampler import SamplerConfig
 from repro.testing import reduced_config
 
 
@@ -19,10 +19,12 @@ def setup():
     return cfg, model, params, Sharder(None, {})
 
 
-def _engine(setup, **kw):
+def _engine(setup, **knobs):
     cfg, model, params, sharder = setup
-    return ServingEngine(model, params, sharder, max_batch=2, max_len=32,
-                         **kw)
+    knobs.setdefault("max_batch", 2)
+    knobs.setdefault("max_len", 32)
+    return ServingEngine.from_plan(ServingPlan(arch=cfg.name, **knobs),
+                                   params, model=model, sharder=sharder)
 
 
 def test_all_requests_complete(setup):
@@ -35,13 +37,12 @@ def test_all_requests_complete(setup):
 
 def test_batched_equals_sequential(setup):
     """Greedy decoding of a request must not depend on its co-tenants."""
-    cfg, model, params, sharder = setup
     prompt = [5, 9, 3, 7]
-    solo = ServingEngine(model, params, sharder, max_batch=1, max_len=32)
+    solo = _engine(setup, max_batch=1)
     r_solo = solo.submit(list(prompt), max_new_tokens=6)
     solo.run()
 
-    multi = ServingEngine(model, params, sharder, max_batch=2, max_len=32)
+    multi = _engine(setup)
     r_a = multi.submit(list(prompt), max_new_tokens=6)
     r_b = multi.submit([2, 4, 6, 8, 10], max_new_tokens=6)
     multi.run()
@@ -74,12 +75,12 @@ def test_prompt_at_capacity_accepted_and_fully_used(setup):
     """A prompt of exactly max_len - 1 tokens is admitted whole: its first
     greedy token matches the same prompt on a roomier engine, so the tail
     provably reached the model."""
-    cfg, model, params, sharder = setup
+    cfg = setup[0]
     prompt = [(7 * i) % cfg.vocab_size for i in range(31)]   # max_len - 1
-    tight = ServingEngine(model, params, sharder, max_batch=1, max_len=32)
+    tight = _engine(setup, max_batch=1)
     r_tight = tight.submit(list(prompt), max_new_tokens=4)
     tight.run()
-    roomy = ServingEngine(model, params, sharder, max_batch=1, max_len=64)
+    roomy = _engine(setup, max_batch=1, max_len=64)
     r_roomy = roomy.submit(list(prompt), max_new_tokens=4)
     roomy.run()
     assert r_tight.done and not r_tight.truncated
@@ -105,9 +106,7 @@ def test_prompt_past_capacity_rejected(setup):
 
 
 def test_prompt_past_capacity_opt_in_truncation(setup, caplog):
-    cfg, model, params, sharder = setup
-    eng = ServingEngine(model, params, sharder, max_batch=1, max_len=32,
-                        truncate_prompts=True)
+    eng = _engine(setup, max_batch=1, truncate_prompts=True)
     with caplog.at_level("WARNING", logger="repro.serving"):
         r = eng.submit(list(range(40)), max_new_tokens=2)
     assert r.truncated and len(r.prompt) == 31

@@ -20,6 +20,7 @@ from repro.dist.sharding import Sharder
 from repro.models import params as pspec
 from repro.models.layers import COMPUTE_DTYPE
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
 from repro.serving.sampler import SamplerConfig, split_and_sample
 from repro.testing import compute_weight_shapes, reduced_config
@@ -81,8 +82,10 @@ def test_engine_serves_the_f32_models_tokens(built):
     model, params = built
     sampler, seed, prompt, n = SamplerConfig(temperature=0.8), 3, \
         [5, 9, 3, 7, 11], 6
-    eng = ServingEngine(model, params, NOSH, max_batch=1, max_len=32,
-                        sampler=sampler, seed=seed)
+    eng = ServingEngine.from_plan(
+        ServingPlan(arch=model.cfg.name, max_batch=1, max_len=32,
+                    temperature=sampler.temperature),
+        params, model=model, sharder=NOSH, seed=seed)
     req = eng.submit(list(prompt), max_new_tokens=n)
     eng.run()
 
@@ -131,7 +134,9 @@ def test_decode_program_converts_no_weight():
     # d_model 128: no weight then has the shape of a leaf read at f32
     model = build_model(reduced_config("rwkv6-1.6b", d_model=128))
     params = model.init(jax.random.PRNGKey(0))
-    eng = ServingEngine(model, params, NOSH, max_batch=2, max_len=32)
+    eng = ServingEngine.from_plan(
+        ServingPlan(arch=model.cfg.name, max_batch=2, max_len=32), params,
+        model=model, sharder=NOSH)
     marked = _marked(model)
     weights = compute_weight_shapes(model)
     assert not weights & {s.shape for s in jax.tree.leaves(

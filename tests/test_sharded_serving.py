@@ -10,6 +10,7 @@ import pytest
 
 from repro.dist.sharding import Sharder, make_rules
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
 from repro.testing import reduced_config
 
@@ -19,8 +20,9 @@ def test_engine_stats_counters_track_load():
     model = build_model(cfg)
     import jax
     params = model.init(jax.random.PRNGKey(0))
-    eng = ServingEngine(model, params, Sharder(None, {}), max_batch=2,
-                        max_len=32)
+    eng = ServingEngine.from_plan(
+        ServingPlan(arch=cfg.name, max_batch=2, max_len=32), params,
+        model=model, sharder=Sharder(None, {}))
     reqs = [eng.submit([1, 2, 3, 4 + i], max_new_tokens=4) for i in range(3)]
     eng.run()
     s = eng.stats()
@@ -49,6 +51,7 @@ import numpy as np
 from repro.dist.sharding import Sharder, make_sharder
 from repro.launch.mesh import make_test_mesh
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
 from repro.testing import reduced_config
 
@@ -85,7 +88,9 @@ for _ in range(4):
 # --- the engine end-to-end under the sharded Sharder: continuous batching
 # completes every request and the counters track the work
 prompts = [[5, 9, 3, 7], [2, 4, 6, 8, 10], [11, 1, 12], [3, 3, 3, 3, 3, 3]]
-eng = ServingEngine(model, params, sharder, max_batch=2, max_len=32)
+eng = ServingEngine.from_plan(
+    ServingPlan(arch=cfg.name, max_batch=2, max_len=32), params,
+    model=model, sharder=sharder)
 reqs = [eng.submit(list(p), max_new_tokens=6) for p in prompts]
 eng.run()
 assert all(r.done and len(r.output) == 6 for r in reqs)

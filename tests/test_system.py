@@ -16,6 +16,7 @@ from repro import hw
 from repro.core import dse
 from repro.core.cells import RNNCellConfig, init_weights, quantize_weights, serve
 from repro.models.lm import build_model
+from repro.plan import ServingPlan
 from repro.serving import ServingEngine
 from repro.testing import reduced_config, smoke_shape
 from repro.train.loop import TrainLoopConfig, train
@@ -50,8 +51,9 @@ def test_train_then_serve_pipeline(tmp_path, nosharder):
     losses = [h["loss"] for h in history]
     assert losses[-1] < losses[0], (losses[0], losses[-1])
 
-    engine = ServingEngine(model, state["params"], nosharder,
-                           max_batch=2, max_len=48)
+    engine = ServingEngine.from_plan(
+        ServingPlan(arch=arch, max_batch=2, max_len=48), state["params"],
+        model=model, sharder=nosharder)
     reqs = [engine.submit([1, 2, 3, 4], max_new_tokens=4) for _ in range(3)]
     engine.run()
     assert all(r.done and len(r.output) == 4 for r in reqs)
