@@ -6,10 +6,20 @@ impl="kernel")`` and the DeepBench benchmark harness.  Block size bh comes
 from the DSE (repro.core.dse) unless overridden — scored at the batch
 actually served, not the DeepBench cell's batch-1 default — or from a
 ``tile_plans`` entry passed as ``plan``.
+
+A request is one dispatch.  The tile is chosen once per (config, batch,
+requested tile) and kept; each (config, tile, persistent, interpret)
+gets one jitted request program, which builds the f32 zero states (and a
+zero ``b_h`` for GRU weights that lack one) inside its trace, so no
+eager device op runs per request.  :data:`METRICS` counts the request
+programs traced (``rnn.programs_built``: one per new input structure,
+so a build after warm-up is a compile in the served path) and the
+requests served (``rnn.requests_served``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Mapping, Optional, Tuple
 
 import jax
@@ -17,9 +27,15 @@ import jax.numpy as jnp
 
 from repro.kernels.dispatch import interpret_mode, tile_arg
 from repro.kernels.fused_rnn.fused_rnn import fused_gru, fused_lstm
+from repro.obs.registry import MetricsRegistry
 from repro.obs.spans import span
 
 F32 = jnp.float32
+
+METRICS = MetricsRegistry()
+_BUILT = METRICS.counter("rnn.programs_built",
+                         "request programs traced (one per input structure)")
+_SERVED = METRICS.counter("rnn.requests_served", "calls of serve()")
 
 
 def _weights_for_kernel(cfg, w: Dict) -> Tuple:
@@ -47,6 +63,43 @@ def default_bh(cfg, batch: int) -> int:
     return best_plan(cfg, max_batch=batch).bh
 
 
+@functools.lru_cache(maxsize=None)
+def _tile(cfg, batch: int, bh: int, persistent: bool) -> int:
+    """The H tile served: the whole of H for the persistent variant, else
+    ``bh`` (or the DSE's tile when 0) snapped to a divisor of H."""
+    from repro.core.dse import snap_tile
+
+    if persistent:
+        return cfg.hidden
+    return snap_tile(cfg.hidden, bh or default_bh(cfg, batch))
+
+
+@functools.lru_cache(maxsize=None)
+def _program(cfg, bh: int, persistent: bool, interpret: bool):
+    """The jitted request program of one cell at one tile:
+    ``(w, x_seq, state) -> y``, with ``state=None`` for zero states."""
+
+    def rnn_request(w, x_seq, state):
+        _BUILT.inc()                             # runs once per trace
+        B, H = x_seq.shape[1], cfg.hidden
+        wx, wh, s_x, s_h = _weights_for_kernel(cfg, w)
+        h0 = jnp.zeros((B, H), F32) if state is None else state[0]
+        if cfg.cell == "lstm":
+            c0 = (state[1] if state is not None and len(state) > 1
+                  else jnp.zeros((B, H), F32))
+            y, _, _ = fused_lstm(x_seq, wx, wh, s_x, s_h, w["b"], h0, c0,
+                                 bh=bh, interpret=interpret,
+                                 persistent=persistent)
+        else:
+            b_h = w["b_h"] if "b_h" in w else jnp.zeros_like(w["b"])
+            y, _ = fused_gru(x_seq, wx, wh, s_x, s_h, w["b"], b_h, h0,
+                             bh=bh, interpret=interpret,
+                             persistent=persistent)
+        return y
+
+    return jax.jit(rnn_request)
+
+
 def serve(cfg, w: Dict, x_seq: jax.Array, *, bh: int = 0,
           state: Optional[Tuple[jax.Array, ...]] = None,
           interpret: Optional[bool] = None,
@@ -57,35 +110,15 @@ def serve(cfg, w: Dict, x_seq: jax.Array, *, bh: int = 0,
     to a divisor of H), ``persistent: true`` selects the weights-resident
     variant (whole-H tile, validated against the VMEM budget by
     ``ServingPlan.validate``)."""
-    from repro.core.dse import snap_tile
-
-    if interpret is None:
-        interpret = interpret_mode()
-    T, B, D = x_seq.shape
-    H = cfg.hidden
     with span("rnn.plan"):
+        if interpret is None:
+            interpret = interpret_mode()
         persistent = bool((plan or {}).get("persistent", False))
-        bh = tile_arg(plan, "bh", bh or 0)
-        if not bh:
-            bh = H if persistent else default_bh(cfg, B)
-        bh = H if persistent else snap_tile(H, bh)
+        bh = _tile(cfg, x_seq.shape[1], tile_arg(plan, "bh", bh or 0),
+                   persistent)
     with span("rnn.operands"):
-        wx, wh, s_x, s_h = _weights_for_kernel(cfg, w)
-        if state is None:
-            h0 = jnp.zeros((B, H), F32)
-            c0 = jnp.zeros((B, H), F32)
-        else:
-            h0 = state[0]
-            c0 = state[1] if len(state) > 1 else jnp.zeros((B, H), F32)
-        b_h = (None if cfg.cell == "lstm"
-               else w.get("b_h", jnp.zeros_like(w["b"])))
+        program = _program(cfg, bh, persistent, interpret)
     with span("rnn.launch"):
-        if cfg.cell == "lstm":
-            y, _, _ = fused_lstm(x_seq, wx, wh, s_x, s_h, w["b"], h0, c0,
-                                 bh=bh, interpret=interpret,
-                                 persistent=persistent)
-        else:
-            y, _ = fused_gru(x_seq, wx, wh, s_x, s_h, w["b"], b_h, h0,
-                             bh=bh, interpret=interpret,
-                             persistent=persistent)
+        y = program(w, x_seq, state)
+    _SERVED.inc()
     return y

@@ -15,9 +15,11 @@ in :data:`SPANS`:
   and the decode-chunk dispatch;
 * ``engine.readback`` — the chunk's blocking ``device_get``;
 * ``engine.bookkeep`` — everything after the readback;
-* ``rnn.plan`` — the fused RNN kernel's tile choice;
-* ``rnn.operands`` — kernel operands: weights, zero states, zero ``b_h``;
-* ``rnn.launch`` — the fused kernel call alone.
+* ``rnn.plan`` — the lookup of the fused RNN kernel's tile, chosen once
+  per configuration;
+* ``rnn.operands`` — the host-side pick of the request program and its
+  arguments (the zero states are built inside the program);
+* ``rnn.launch`` — the one dispatch of the request program.
 """
 
 from __future__ import annotations

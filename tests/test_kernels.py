@@ -194,6 +194,83 @@ def test_fused_rnn_persistent_changes_lowering():
     assert text({"persistent": True}) != text({"bh": 128})
 
 
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+@pytest.mark.parametrize("H", [128, 256])
+@pytest.mark.parametrize("given_state", [False, True])
+def test_serve_request_program_matches_direct_kernel(cell, H, given_state):
+    """serve()'s request program builds the zero states inside its trace;
+    its y is bit-identical to a direct kernel call with explicit f32
+    states at the DSE's tile for the served batch."""
+    from repro.core.dse import best_plan, snap_tile
+    from repro.kernels.fused_rnn.fused_rnn import fused_gru, fused_lstm
+    B, T = 2, 3
+    cfg = RNNCellConfig(cell, H, timesteps=T, batch=B, precision="int8")
+    w = quantize_weights(cfg, init_weights(cfg, jax.random.PRNGKey(10)))
+    x = jax.random.normal(jax.random.PRNGKey(11), (T, B, cfg.d),
+                          jnp.bfloat16)
+    h0 = c0 = jnp.zeros((B, H), jnp.float32)
+    state = None
+    if given_state:
+        k = jax.random.split(jax.random.PRNGKey(12))
+        h0, c0 = (0.5 * jax.random.normal(k[i], (B, H)) for i in range(2))
+        state = (h0, c0) if cell == "lstm" else (h0,)
+    bh = snap_tile(H, best_plan(cfg, max_batch=B).bh)
+    wx, wh, sx, sh = rnn_ops._weights_for_kernel(cfg, w)
+    y = rnn_ops.serve(cfg, w, x, state=state, interpret=True)
+    if cell == "lstm":
+        y_direct, _, _ = fused_lstm(x, wx, wh, sx, sh, w["b"], h0, c0,
+                                    bh=bh, interpret=True)
+    else:
+        y_direct, _ = fused_gru(x, wx, wh, sx, sh, w["b"], w["b_h"], h0,
+                                bh=bh, interpret=True)
+    assert y.dtype == y_direct.dtype and y.shape == y_direct.shape
+    assert (np.asarray(y, np.float32) == np.asarray(y_direct,
+                                                    np.float32)).all()
+
+
+def test_serve_caches_plan_and_program(monkeypatch):
+    """Repeated requests of one configuration run the DSE once and build
+    one request program; a given state or a second configuration builds
+    another; every call counts as a request served."""
+    import repro.core.dse as dse
+    calls = []
+    orig = dse.best_plan
+
+    def counted(*args, **kwargs):
+        calls.append(args[0])
+        return orig(*args, **kwargs)
+
+    monkeypatch.setattr(dse, "best_plan", counted)
+    built = rnn_ops.METRICS["rnn.programs_built"]
+    served = rnn_ops.METRICS["rnn.requests_served"]
+    # timesteps=7 keeps these configurations out of every other test's
+    # cached plans and programs in this process
+    cfg = RNNCellConfig("gru", 128, timesteps=7, batch=1, precision="int8")
+    w = quantize_weights(cfg, init_weights(cfg, jax.random.PRNGKey(13)))
+    x = jax.random.normal(jax.random.PRNGKey(14), (2, 1, cfg.d),
+                          jnp.bfloat16)
+    b0, s0 = built.value, served.value
+    ys = [rnn_ops.serve(cfg, w, x, interpret=True) for _ in range(3)]
+    assert calls == [cfg]
+    assert built.value - b0 == 1 and served.value - s0 == 3
+    assert all((np.asarray(y) == np.asarray(ys[0])).all() for y in ys)
+
+    rnn_ops.serve(cfg, w, x, interpret=True,
+                  state=(jnp.zeros((1, 128), jnp.float32),))
+    assert built.value - b0 == 2 and calls == [cfg]
+    rnn_ops.serve(cfg, w, x, interpret=True,
+                  state=(jnp.ones((1, 128), jnp.float32),))
+    assert built.value - b0 == 2
+
+    cfg2 = RNNCellConfig("lstm", 128, timesteps=7, batch=1,
+                         precision="int8")
+    w2 = quantize_weights(cfg2, init_weights(cfg2, jax.random.PRNGKey(15)))
+    for _ in range(2):
+        rnn_ops.serve(cfg2, w2, x, interpret=True)
+    assert calls == [cfg, cfg2]
+    assert built.value - b0 == 3 and served.value - s0 == 7
+
+
 def test_flash_attention_pos_matches_iota_path():
     """With explicit iota positions the position-array kernel must equal
     the iota-masking kernel bitwise — same masks, same math."""
